@@ -3,7 +3,8 @@ the pure-jnp oracle. Prints ``name,us_per_call,derived`` CSV.
 
 On this CPU container the *oracle* timing is the meaningful number (it is
 what the FL loop runs); interpret-mode timings are recorded for reference
-only — on TPU the compiled kernels take over (kernels/ops.py dispatch).
+only — on TPU the runner's hier route takes the compiled aio_absorb /
+aio_merge (``use_kernel`` in core/aggregation.py, chosen by the caller).
 """
 from __future__ import annotations
 

@@ -115,6 +115,8 @@ def main(argv=None) -> None:
     ap.add_argument("--no-trajectory", action="store_true",
                     help="do not append BENCH_<section>.json records")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.fast:
         os.environ["BENCH_SCALE"] = "fast"
     elif args.full:

@@ -43,8 +43,6 @@ RULE_ID = "use-after-donate"
 KNOWN_DONATING: dict[str, tuple[tuple[int, str], ...]] = {
     "aio_absorb": ((0, ""), (1, "")),
     "aio_merge": ((0, ""), (1, "")),
-    "aio_absorb_op": ((0, ""), (1, "")),
-    "aio_merge_op": ((0, ""), (1, "")),
     "absorb_trees": ((0, ""), (1, "")),
     "merge_trees": ((0, ""), (1, "")),
     "partial_absorb": ((0, ".num"), (0, ".den")),

@@ -8,7 +8,7 @@ interpret-mode test, is unverifiable on this container and ships on
 trust.  This rule closes the loop statically:
 
 * an *exported kernel* is a public module-level function in a
-  ``kernels/`` module (other than ``ref.py`` / ``ops.py``) that invokes
+  ``kernels/`` module (other than ``ref.py``) that invokes
   ``pl.pallas_call`` directly, or publicly wraps one that does;
 * every exported kernel must be a key of the ``ORACLES`` table in the
   sibling ``kernels/ref.py`` (falling back to a ``<kernel>_ref``
@@ -30,7 +30,7 @@ from repro.analysis.engine import Finding, SourceFile
 
 RULE_ID = "kernel-oracle-pairing"
 
-NON_KERNEL_FILES = {"ref.py", "ops.py", "__init__.py"}
+NON_KERNEL_FILES = {"ref.py", "__init__.py"}
 
 
 def _is_kernels_module(src: SourceFile) -> bool:

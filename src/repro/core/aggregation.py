@@ -70,17 +70,22 @@ def fedavg_coefficients(data_sizes) -> jax.Array:
 
 
 def aio_aggregate(updates: Sequence[PyTree], masks: Sequence[PyTree],
-                  weights: jax.Array, *, use_kernel: bool = False) -> PyTree:
-    """Eq. 5 over pytrees. updates/masks: per-device, same treedef."""
+                  weights: jax.Array, *, use_kernel: bool = False,
+                  interpret: bool = False) -> PyTree:
+    """Eq. 5 over pytrees. updates/masks: per-device, same treedef.
+
+    ``use_kernel`` runs the compiled Pallas kernel (``interpret`` runs it
+    in the Pallas interpreter instead, for tests off the chip)."""
     stacked_u = jax.tree.map(lambda *xs: jnp.stack(xs), *updates)
     stacked_m = jax.tree.map(lambda *xs: jnp.stack(xs), *masks)
 
     def agg(u, m):
         if use_kernel:
-            from repro.kernels.ops import aio_aggregate_op
+            from repro.kernels import aio_agg
             shape = u.shape[1:]
-            flat = aio_aggregate_op(u.reshape(u.shape[0], -1),
-                                    m.reshape(m.shape[0], -1), weights)
+            flat = aio_agg.aio_aggregate(u.reshape(u.shape[0], -1),
+                                         m.reshape(m.shape[0], -1), weights,
+                                         interpret=interpret)
             return flat.reshape(shape)
         w = weights.reshape((-1,) + (1,) * (u.ndim - 1))
         num = jnp.sum(w * m * u, axis=0)
@@ -123,30 +128,35 @@ def partial_init(template: PyTree) -> PartialAgg:
                       den=jax.tree.map(jnp.zeros_like, zeros), count=0)
 
 
-def _absorb_leaves(num, den, u, m, w, *, use_kernel: bool):
+def _absorb_leaves(num, den, u, m, w, *, use_kernel: bool,
+                   interpret: bool):
     if use_kernel:
-        from repro.kernels.ops import aio_absorb_op
+        from repro.kernels import aio_agg
         shape = u.shape
-        n2, d2 = aio_absorb_op(num.reshape(-1), den.reshape(-1),
-                               u.reshape(-1), m.reshape(-1), w)
+        n2, d2 = aio_agg.aio_absorb(num.reshape(-1), den.reshape(-1),
+                                    u.reshape(-1), m.reshape(-1), w,
+                                    interpret=interpret)
         return n2.reshape(shape), d2.reshape(shape)
     wm = w * m.astype(jnp.float32)
     return num + wm * u.astype(jnp.float32), den + wm
 
 
 def absorb_trees(num: PyTree, den: PyTree, values: PyTree, mask: PyTree,
-                 weight, *, use_kernel: bool = False
-                 ) -> tuple[PyTree, PyTree]:
+                 weight, *, use_kernel: bool = False,
+                 interpret: bool = False) -> tuple[PyTree, PyTree]:
     """The absorb update rule over (num, den) pytrees — jit-compatible.
 
     Single home of the ``num += w*m*u, den += w*m`` math; both
     :func:`partial_absorb` and the runner's jit'd edge absorb route
     through here so the rule cannot drift between call sites.
+    ``use_kernel`` runs the compiled Pallas ``aio_absorb``, which donates
+    (num, den); ``interpret`` runs it in the Pallas interpreter (tests).
     """
     w = jnp.asarray(weight, jnp.float32)
     pairs = jax.tree.map(
         lambda n, d, u, m: _absorb_leaves(n, d, u, m, w,
-                                          use_kernel=use_kernel),
+                                          use_kernel=use_kernel,
+                                          interpret=interpret),
         num, den, values, mask)
     treedef = jax.tree.structure(num)
     flat = treedef.flatten_up_to(pairs)
@@ -168,7 +178,8 @@ def partial_absorb(part: PartialAgg, values: PyTree, mask: PyTree,
 
 
 def merge_trees(num_a: PyTree, den_a: PyTree, num_b: PyTree, den_b: PyTree,
-                *, use_kernel: bool = False) -> tuple[PyTree, PyTree]:
+                *, use_kernel: bool = False, interpret: bool = False
+                ) -> tuple[PyTree, PyTree]:
     """The merge update rule over (num, den) pytrees — jit-compatible.
 
     Single home of the element-wise pair addition; :func:`partial_merge`
@@ -176,15 +187,17 @@ def merge_trees(num_a: PyTree, den_a: PyTree, num_b: PyTree, den_b: PyTree,
     here.  Under ``jax.jit(..., donate_argnums=(0, 1))`` the ``a``-side
     accumulator is updated in place instead of reallocated per arrival
     (the Pallas kernel route aliases its outputs onto the same operands
-    via ``input_output_aliases``).
+    via ``input_output_aliases``); ``interpret`` runs the kernel in the
+    Pallas interpreter (tests).
     """
     if use_kernel:
-        from repro.kernels.ops import aio_merge_op
+        from repro.kernels import aio_agg
 
         def leaf(na, da, nb, db):
             shape = na.shape
-            n, d = aio_merge_op(na.reshape(-1), da.reshape(-1),
-                                nb.reshape(-1), db.reshape(-1))
+            n, d = aio_agg.aio_merge(na.reshape(-1), da.reshape(-1),
+                                     nb.reshape(-1), db.reshape(-1),
+                                     interpret=interpret)
             return n.reshape(shape), d.reshape(shape)
 
         pairs = jax.tree.map(leaf, num_a, den_a, num_b, den_b)

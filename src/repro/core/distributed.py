@@ -205,13 +205,11 @@ def mesh_cell_aggregate(u: jax.Array, m: jax.Array, w: jax.Array, mesh, *,
 
     Equals the flat ``aio_aggregate_stacked`` oracle up to float
     reordering, for any cell partitioning (the monoid is commutative).
-    Built on :func:`repro.utils.compat.shard_map`, so it runs on both
-    JAX 0.4.x and >= 0.6.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.ref import aio_absorb_ref
-    from repro.utils.compat import shard_map
 
     def per_cell(u_c, m_c, w_c):
         # shard-local streaming absorb: one pass over the cell's clients,

@@ -8,7 +8,9 @@ single pass: one read of (values, norms-row-map, randoms), one write of
 stage, a ~2.5x HBM-traffic reduction by construction.
 
 Layout: x is the (K, ksize) kernel-major view of one leaf; per-row norms
-and the global threshold/scalars ride in small side inputs.
+ride as a (K, 1) column in (BK, 1) blocks (a 1-D (BK,) block does not
+match the TPU's 1-D tiling of 1024 lanes), and the global
+threshold/scalars in a small side input.
 """
 from __future__ import annotations
 
@@ -24,8 +26,8 @@ BC = 512
 
 def _fused_kernel(s_ref, n_ref, x_ref, r_ref, q_ref, l_ref):
     thr, u_min, u_max, L = s_ref[0], s_ref[1], s_ref[2], s_ref[3]
-    keep = (n_ref[...] >= thr).astype(jnp.float32)     # (BK,)
-    v = x_ref[...].astype(jnp.float32) * keep[:, None]
+    keep = (n_ref[...] >= thr).astype(jnp.float32)     # (BK, 1)
+    v = x_ref[...].astype(jnp.float32) * keep
     av = jnp.abs(v)
     span = jnp.maximum(u_max - u_min, 1e-20)
     step = span / L
@@ -64,7 +66,7 @@ def fused_sparsify_quantize(x: jax.Array, norms: jax.Array, thr: jax.Array,
         grid=(Kp // bk, Cp // bc),
         in_specs=[
             pl.BlockSpec((4,), lambda i, j: (0,)),
-            pl.BlockSpec((bk,), lambda i, j: (i,)),
+            pl.BlockSpec((bk, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bk, bc), lambda i, j: (i, j)),
             pl.BlockSpec((bk, bc), lambda i, j: (i, j)),
         ],
@@ -77,7 +79,7 @@ def fused_sparsify_quantize(x: jax.Array, norms: jax.Array, thr: jax.Array,
             jax.ShapeDtypeStruct((Kp, Cp), jnp.int32),
         ],
         interpret=interpret,
-    )(scalars, norms.astype(jnp.float32), x, rand)
+    )(scalars, norms.astype(jnp.float32).reshape(Kp, 1), x, rand)
     return q[:K, :C], lvl[:K, :C]
 
 

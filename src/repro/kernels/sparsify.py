@@ -8,6 +8,10 @@ masking) — memory-bound over hundreds of MB. Two kernels:
   arbitrarily long rows stream through a fixed (BK, BC) VMEM window.
 * ``threshold_apply`` — elementwise ``x * (norm[row] >= thr)`` over the same
   tiling, fused mask materialization.
+
+Per-row vectors (sums of squares, norms, keep masks) travel as ``(K, 1)``
+columns in ``(BK, 1)`` blocks: a 1-D ``(BK,)`` block with BK < 1024 does
+not match the TPU's 1-D tiling of 1024 lanes, and Mosaic refuses it.
 """
 from __future__ import annotations
 
@@ -29,7 +33,7 @@ def _sumsq_kernel(x_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.sum(x * x, axis=1)
+    o_ref[...] += jnp.sum(x * x, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bk", "bc"))
@@ -48,11 +52,11 @@ def kernel_sumsq(x: jax.Array, *, interpret: bool = False, bk: int = BK,
         _sumsq_kernel,
         grid=(Kp // bk, Cp // bc),
         in_specs=[pl.BlockSpec((bk, bc), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((bk,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Kp,), jnp.float32),
+        out_specs=pl.BlockSpec((bk, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Kp, 1), jnp.float32),
         interpret=interpret,
     )(x)
-    return out[:K]
+    return out[:K, 0]
 
 
 def kernel_l2(x: jax.Array, *, interpret: bool = False) -> jax.Array:
@@ -60,9 +64,9 @@ def kernel_l2(x: jax.Array, *, interpret: bool = False) -> jax.Array:
 
 
 def _threshold_kernel(thr_ref, x_ref, n_ref, xo_ref, mo_ref):
-    keep = (n_ref[...] >= thr_ref[0]).astype(jnp.float32)     # (BK,)
+    keep = (n_ref[...] >= thr_ref[0]).astype(jnp.float32)     # (BK, 1)
     xo_ref[...] = (x_ref[...].astype(jnp.float32)
-                   * keep[:, None]).astype(xo_ref.dtype)
+                   * keep).astype(xo_ref.dtype)
     mo_ref[...] = keep
 
 
@@ -86,16 +90,17 @@ def threshold_apply(x: jax.Array, norms: jax.Array, thr: jax.Array, *,
         in_specs=[
             pl.BlockSpec((1,), lambda i, j: (0,)),
             pl.BlockSpec((bk, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((bk,), lambda i, j: (i,)),
+            pl.BlockSpec((bk, 1), lambda i, j: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bk, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((bk,), lambda i, j: (i,)),
+            pl.BlockSpec((bk, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Kp, Cp), x.dtype),
-            jax.ShapeDtypeStruct((Kp,), jnp.float32),
+            jax.ShapeDtypeStruct((Kp, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(thr.reshape(1).astype(jnp.float32), x, norms.astype(jnp.float32))
-    return xo[:K, :C], mo[:K]
+    )(thr.reshape(1).astype(jnp.float32), x,
+      norms.astype(jnp.float32).reshape(Kp, 1))
+    return xo[:K, :C], mo[:K, 0]

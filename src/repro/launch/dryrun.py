@@ -1,11 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # Multi-pod dry-run: lower + compile every (arch x input-shape x mesh).
 _DOC = """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh).
 
-The two lines above MUST run before any jax import — jax locks the device
-count at first init. 512 host devices back the production meshes:
+The lines above MUST run before any jax import — jax locks the device
+count at first init, and the host devices are the CPU's (on a machine
+with a chip JAX would otherwise take the TPU). 512 host devices back the
+production meshes:
 16x16 (single pod) and 2x16x16 (two pods).
 
 Usage:

@@ -7,6 +7,14 @@ touch jax device state (smoke tests see 1 CPU device; only dryrun.py forces
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the logical-axis rules place
+    arrays with ``with_sharding_constraint``, which refuses ``Explicit``
+    axes (``jax.make_mesh``'s default)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, data_par: int = 16):
@@ -17,13 +25,13 @@ def make_production_mesh(*, multi_pod: bool = False, data_par: int = 16):
     assert data_par * model_par == 256, data_par
     shape = (2, data_par, model_par) if multi_pod else (data_par, model_par)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist, as a (1, N) data/model mesh — CPU tests."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return _auto_mesh((1, n), ("data", "model"))
 
 
 def describe(mesh) -> str:

@@ -21,6 +21,7 @@ import numpy as np
 from repro import sharding as shd
 from repro.configs import get_config
 from repro.core import shrinking
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model
 
@@ -59,6 +60,7 @@ def main():
                     help="anycost sub-model width for serving (Fig. 5d)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -21,12 +21,12 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import sharding as shd
 from repro.configs.base import ArchConfig, InputShape
 from repro.core.distributed import anycost_gradient_sync
-from repro.utils.compat import shard_map
 from repro.models import layers as L
 from repro.models.registry import Model, loss_fn
 from repro.train.optimizer import Optimizer
@@ -213,6 +213,9 @@ def rules_for(shape: InputShape, grad_sync: str = "auto") -> dict:
         # vocab dim, shard the feature dim over model instead.
         rules["vocab"] = None
         rules["embed_fsdp"] = "model"
+        # likewise the loss's label gather on logits sharded over both
+        # data and model (vocab): keep the logits vocab dim whole.
+        rules["vocab_act"] = None
     if shape.kind == "decode":
         # weight-stationary expert sharding for inference (§Perf P1.2):
         # shard expert d_ff over data instead of ZeRO on the input dim so

@@ -28,6 +28,7 @@ from repro import sharding as shd
 from repro.configs import get_config, TRAIN_4K
 from repro.configs.base import InputShape
 from repro.data.synthetic import make_token_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step, param_shardings, \
     opt_state_shardings, batch_shardings, input_specs
@@ -168,10 +169,13 @@ def run_fl(args):
     from repro.sysmodel.population import FleetConfig
     from repro.telemetry import NULL_TELEMETRY, Telemetry, build_manifest
     from repro.train.fl_loop import FLRunConfig, PHASES
+    family = get_config(args.arch).family
+    if family != "cnn":
+        raise SystemExit(f"--mode fl trains the image CNNs (fmnist-cnn, "
+                         f"vgg9-cifar); --arch {args.arch} is a {family} "
+                         f"model")
     run_cfg = FLRunConfig(
-        arch=args.arch if args.arch.endswith(("cnn", "cifar"))
-        else "fmnist-cnn",
-        method=args.method, rounds=args.rounds, lr=args.lr,
+        arch=args.arch, method=args.method, rounds=args.rounds, lr=args.lr,
         seed=args.seed, iid=not args.non_iid, n_train=args.n_train,
         n_test=args.n_test, eval_every=args.eval_every)
     fleet = FleetConfig(n_devices=args.devices,
@@ -258,7 +262,7 @@ def run_fl(args):
     return hist
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="fl", choices=["fl", "pod"])
     ap.add_argument("--arch", default="fmnist-cnn")
@@ -338,8 +342,8 @@ def main():
                     help="hierarchical aggregation route: streaming "
                          "edge fold (default), the batched (I,N) Eq.-5 "
                          "oracle, or core/distributed.mesh_cell_aggregate"
-                         " over a 'cell' mesh axis (falls back to "
-                         "streaming on a single visible device)")
+                         " over a 'cell' mesh axis (needs >= 2 "
+                         "devices)")
     # ---- mobility & handover
     ap.add_argument("--mobility", default="static",
                     choices=["static", "random_waypoint", "gauss_markov",
@@ -454,7 +458,12 @@ def main():
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--checkpoint", default=None)
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
     # mode-dependent lr default: a None sentinel (not value equality, which
     # would also clobber an explicit --lr equal to the other mode's default)
     if args.lr is None:

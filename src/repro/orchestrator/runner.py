@@ -234,8 +234,7 @@ class Simulation:
         # mobility); reuse the Fleet's copy rather than re-reading it
         self.scenario = self.fleet.scenario
         # aggregation route for hierarchical merges (run_orchestrated
-        # overrides from OrchestratorConfig.agg_route; the mesh route
-        # needs >= 2 visible devices to map cells onto a mesh axis)
+        # overrides from OrchestratorConfig.agg_route)
         self.agg_route = "streaming"
 
         # ---- learning-dynamics diagnostics.  Only an enabled session
@@ -485,13 +484,13 @@ class Simulation:
 
     def resolve_agg_route(self, route: str) -> str:
         """The mesh route shards cells over a mesh axis; with a single
-        visible device there is nothing to shard over — fall back to the
-        host-side streaming fold (satisfying the same math) loudly."""
+        visible device there is nothing to shard over, and the run is
+        refused rather than quietly taking another route."""
         if route == "mesh" and len(jax.devices()) < 2:
-            print("[topology] warning: --agg-route mesh needs >= 2 "
-                  "devices to map cells onto a mesh axis; falling back "
-                  "to the streaming edge fold")
-            route = "streaming"
+            raise ValueError(
+                f"--agg-route mesh maps cells onto a mesh axis and needs "
+                f">= 2 devices; JAX sees {len(jax.devices())} "
+                f"({jax.devices()[0].platform})")
         if route != "streaming" and self.topo is not None \
                 and (self.topo.backhaul.codec != "f32"
                      or self.codec_ef is not None):
@@ -972,6 +971,7 @@ def _run_round_based(sim: Simulation, policy, orch: OrchestratorConfig,
             break
     hist.trace = queue.trace_signature()
     hist.dispatch_log = sim.dispatch_log
+    hist.params = params
     return hist
 
 
@@ -1348,6 +1348,7 @@ def _run_fedbuff(sim: Simulation, policy, orch: OrchestratorConfig,
     hist.trace = queue.trace_signature()
     hist.dispatch_log = sim.dispatch_log
     hist.peak_inflight = peak_inflight
+    hist.params = current
     return hist
 
 

@@ -148,10 +148,20 @@ def sharding_for(shape: Sequence[int],
 
 
 def lc(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:
-    """Logical sharding constraint; identity outside a sharding context."""
+    """Logical sharding constraint; identity outside a sharding context.
+
+    Inside a ``shard_map`` region with manual axes (the anycost pod sync)
+    the region traces against an abstract mesh whose manual axes differ
+    from the concrete one, so the constraint is built on that mesh; the
+    rules must not name the manual axes there (see ``steps.rules_for``).
+    """
     if not active():
         return x
     assert len(axes) == x.ndim, (axes, x.shape)
+    region = jax.sharding.get_abstract_mesh()
+    if region.manual_axes:
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(region, safe_spec(x.shape, axes)))
     return jax.lax.with_sharding_constraint(x, sharding_for(x.shape, axes))
 
 

@@ -4,9 +4,8 @@ The simulator's own telemetry is simulated-time; this is the *host*
 side: wrapping a run in ``profile_trace`` captures an XLA/TensorBoard
 profile (kernel-level timing of the vmapped client pool, the donated
 absorb/merge jits, the Pallas kernels) under ``<out_dir>/jax_profile``.
-Strictly opt-in (``--jax-profile``) and failure-tolerant: a jaxlib
-without profiler support degrades to a no-op with a warning instead of
-killing the run.
+Strictly opt-in (``--jax-profile``).  A profiler that cannot start or
+stop fails the run: a run asked for a profile never goes on without one.
 """
 from __future__ import annotations
 
@@ -19,24 +18,15 @@ from typing import Iterator, Optional
 def profile_trace(out_dir: Optional[str], enabled: bool = True
                   ) -> Iterator[Optional[str]]:
     """Start/stop ``jax.profiler`` around the body; yields the profile
-    directory (None when disabled or unavailable)."""
+    directory (None when disabled)."""
     if not enabled or out_dir is None:
         yield None
         return
+    import jax
     prof_dir = os.path.join(out_dir, "jax_profile")
-    try:
-        import jax
-        os.makedirs(prof_dir, exist_ok=True)
-        jax.profiler.start_trace(prof_dir)
-    except Exception as e:                  # pragma: no cover
-        print(f"[telemetry] warning: jax.profiler unavailable ({e}); "
-              f"running without a host profile")
-        yield None
-        return
+    os.makedirs(prof_dir, exist_ok=True)
+    jax.profiler.start_trace(prof_dir)
     try:
         yield prof_dir
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:              # pragma: no cover
-            print(f"[telemetry] warning: jax.profiler stop failed ({e})")
+        jax.profiler.stop_trace()

@@ -175,6 +175,9 @@ class History:
     # the MetricsRegistry backing every RoundLog in ``rounds`` (each row
     # is a from_registry view over it); always present after a run
     registry: Optional[Any] = None
+    # the global model at the end of the run (in the server's sorted
+    # channel frame under EMS)
+    params: Optional[PyTree] = dataclasses.field(default=None, repr=False)
 
     def log_round(self, round_idx: int, **fields) -> "RoundLog":
         """Gauge every field into the registry, then append + return the

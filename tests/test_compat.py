@@ -1,20 +1,19 @@
-"""The shard_map compatibility shim, exercised under the *installed* JAX
-(whichever side of the 0.6 API move it is on), plus the fast in-process
-coverage of the mesh-mapped edge-cell aggregation route."""
+"""The ``jax.shard_map`` surface this repo relies on (keyword specs,
+``check_vma``, partial-manual ``axis_names`` over an ``Auto`` mesh), plus
+the fast in-process coverage of the mesh-mapped edge-cell aggregation
+route."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.core.aggregation import aio_aggregate_stacked
 from repro.core.distributed import mesh_cell_aggregate
-from repro.utils.compat import shard_map
+from jax import shard_map
 
 
-def test_shim_resolves_on_installed_jax():
-    """The wrapper must build a working shard_map whether or not
-    ``jax.shard_map`` exists (the 0.4.37 container only has the
-    experimental spelling with ``check_rep``/``auto`` kwargs)."""
+def test_shard_map_full_manual_psum():
+    """A full-manual psum over a one-device axis is the identity."""
     mesh = jax.make_mesh((1,), ("pod",))
     out = shard_map(lambda x: jax.lax.psum(x, "pod"), mesh=mesh,
                     in_specs=(P(),), out_specs=P(),
@@ -22,7 +21,7 @@ def test_shim_resolves_on_installed_jax():
     np.testing.assert_allclose(np.asarray(out), np.arange(4.0))
 
 
-def test_shim_translates_check_vma_both_values():
+def test_shard_map_check_vma_both_values():
     mesh = jax.make_mesh((1,), ("x",))
     for check in (True, False):
         out = shard_map(lambda a: a * 2.0, mesh=mesh, in_specs=(P("x"),),
@@ -31,23 +30,18 @@ def test_shim_translates_check_vma_both_values():
 
 
 def test_shim_axis_names_subset():
-    """Partial-manual spelling: ``axis_names`` names the manual axes; on
-    old JAX the complement must land in ``auto=``.  A TypeError here
-    would mean the kwarg translation is wrong; NotImplementedError means
-    the installed backend can't *execute* partial-manual regions (CPU on
-    0.4.x) — the translation itself was accepted, which is what this
-    test pins down."""
-    import pytest
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    """Partial-manual spelling: ``axis_names`` names the manual axes and
+    the rest stay under the compiler, which needs them ``Auto`` (the
+    anycost pod sync in ``launch/steps.py`` runs this way, inside its
+    jitted step: eager dispatch of a partial-manual region with
+    ``check_vma=False`` is refused by JAX 0.9)."""
+    mesh = jax.make_mesh((1, 1), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     mapped = shard_map(lambda x: jax.lax.psum(x, "pod"), mesh=mesh,
                        axis_names=frozenset({"pod"}),
                        in_specs=(P("pod"),), out_specs=P(),
                        check_vma=False)
-    try:
-        out = mapped(jnp.ones((1, 3)))
-    except NotImplementedError:
-        pytest.skip("installed backend cannot execute partial-manual "
-                    "shard_map regions (kwargs were accepted)")
+    out = jax.jit(mapped)(jnp.ones((1, 3)))
     assert out.shape == (1, 3)
 
 

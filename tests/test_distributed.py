@@ -21,7 +21,7 @@ from repro.core.aggregation import aio_aggregate_stacked
 from repro.core.distributed import (anycost_gradient_sync,
                                     mean_gradient_sync,
                                     mesh_cell_aggregate)
-from repro.utils.compat import shard_map
+from jax import shard_map
 
 mesh = jax.make_mesh((2,), ("pod",))
 g = {"w": (jnp.arange(64, dtype=jnp.float32).reshape(2, 32) + 1.0) / 64.0,

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import distributed as D
-from repro.utils.compat import shard_map
+from jax import shard_map
 
 
 P = jax.sharding.PartitionSpec

@@ -1,5 +1,7 @@
 """Per-kernel validation: Pallas (interpret mode on CPU) vs pure-jnp oracle,
 swept over shapes and dtypes."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -117,36 +119,42 @@ def test_prob_quantize(N, levels):
 
 
 def test_ops_dispatch_matches_ref():
-    from repro.kernels import ops
+    """aggregation's kernel route (flatten each leaf, run the Pallas
+    kernel, restore the shape) against its jnp route."""
+    from repro.core import aggregation
     ks = jax.random.split(KEY, 3)
-    u = jax.random.normal(ks[0], (4, 300))
-    m = (jax.random.uniform(ks[1], (4, 300)) > 0.5).astype(jnp.float32)
+    u = jax.random.normal(ks[0], (4, 30, 10))
+    m = (jax.random.uniform(ks[1], (4, 30, 10)) > 0.5).astype(jnp.float32)
     w = jax.random.uniform(ks[2], (4,))
-    a = ops.aio_aggregate_op(u, m, w, use_pallas=False)
-    b = ops.aio_aggregate_op(u, m, w, use_pallas=True)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    ups = [{"k": u[i]} for i in range(4)]
+    mks = [{"k": m[i]} for i in range(4)]
+    a = aggregation.aio_aggregate(ups, mks, w)
+    b = aggregation.aio_aggregate(ups, mks, w, use_kernel=True,
+                                  interpret=True)
+    np.testing.assert_allclose(np.asarray(a["k"]), np.asarray(b["k"]),
+                               atol=1e-5)
 
 
 def test_ops_absorb_merge_dispatch_matches_ref():
-    from repro.kernels import ops
+    from repro.core import aggregation
     ks = jax.random.split(KEY, 4)
-    num = jax.random.normal(ks[0], (300,))
-    den = jax.random.uniform(ks[1], (300,))
-    u = jax.random.normal(ks[2], (300,))
-    m = (jax.random.uniform(ks[3], (300,)) > 0.5).astype(jnp.float32)
-    a = ops.aio_absorb_op(num, den, u, m, 0.6, use_pallas=False)
-    # the pallas routes donate their accumulator operands — feed copies.
-    # use_pallas=False above is the non-donating ref route, so num/den
-    # are still live here.
-    # repro: ignore[use-after-donate]
-    b = ops.aio_absorb_op(jnp.copy(num), jnp.copy(den), u, m, 0.6,
-                          use_pallas=True)
+    num = {"k": jax.random.normal(ks[0], (30, 10))}
+    den = {"k": jax.random.uniform(ks[1], (30, 10))}
+    u = {"k": jax.random.normal(ks[2], (30, 10))}
+    m = {"k": (jax.random.uniform(ks[3], (30, 10)) > 0.5
+               ).astype(jnp.float32)}
+    copy = functools.partial(jax.tree.map, jnp.copy)
+    # the kernel routes donate their accumulator operands: every call
+    # gets copies, so num/den stay live for the next one
+    a = aggregation.absorb_trees(copy(num), copy(den), u, m, 0.6)
+    b = aggregation.absorb_trees(copy(num), copy(den), u, m, 0.6,
+                                 use_kernel=True, interpret=True)
     for x, y in zip(a, b):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5)
-    # repro: ignore[use-after-donate] — same: ref route does not donate
-    a2 = ops.aio_merge_op(num, den, u, m, use_pallas=False)
-    # repro: ignore[use-after-donate] — same: ref route does not donate
-    b2 = ops.aio_merge_op(jnp.copy(num), jnp.copy(den), u, m,
-                          use_pallas=True)
+        np.testing.assert_allclose(np.asarray(x["k"]), np.asarray(y["k"]),
+                                   atol=1e-5)
+    a2 = aggregation.merge_trees(copy(num), copy(den), u, m)
+    b2 = aggregation.merge_trees(copy(num), copy(den), u, m,
+                                 use_kernel=True, interpret=True)
     for x, y in zip(a2, b2):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5)
+        np.testing.assert_allclose(np.asarray(x["k"]), np.asarray(y["k"]),
+                                   atol=1e-5)

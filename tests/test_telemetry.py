@@ -338,3 +338,20 @@ def test_session_flush_writes_bundle(tmp_path):
 def test_session_flush_without_dir_raises():
     with pytest.raises(ValueError, match="out_dir"):
         Telemetry().flush()
+
+
+def test_profile_trace_fails_when_profiler_cannot_start(tmp_path,
+                                                        monkeypatch):
+    """--jax-profile on a jaxlib whose profiler cannot start fails the
+    run instead of running on without a profile."""
+    import jax
+
+    from repro.telemetry import profile_trace
+
+    def refuse(_):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with profile_trace(str(tmp_path)):
+            pass

@@ -369,12 +369,12 @@ def test_agg_route_batched_matches_streaming():
         assert a.n_cells_reporting == b.n_cells_reporting
 
 
-def test_agg_route_mesh_falls_back_on_one_device(capsys):
-    topo = TopologyConfig(kind="hier", n_cells=2)
-    hs = _run(topology=topo, n=4)
+def test_agg_route_mesh_refuses_one_device():
+    """With one visible device the mesh route has no axis to shard cells
+    over: the run is refused, never quietly routed through the streaming
+    fold."""
     if len(jax.devices()) >= 2:
-        pytest.skip("multi-device host: no fallback to observe")
-    hm = _run(topology=topo, n=4, agg_route="mesh")
-    out = capsys.readouterr().out
-    assert "falling back" in out
-    assert hm.best_acc == hs.best_acc          # identical streaming math
+        pytest.skip("multi-device host: the mesh route is available")
+    topo = TopologyConfig(kind="hier", n_cells=2)
+    with pytest.raises(ValueError, match="needs >= 2 devices"):
+        _run(topology=topo, n=4, agg_route="mesh")
