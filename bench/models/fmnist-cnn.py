@@ -1,0 +1,26 @@
+"""FedAvg CNN (McMahan et al. 2017), as AnycostFL section V-A trains it.
+
+Layer table read by ``bench/reference.py``: NHWC images, 'SAME' 5x5
+convolutions with bias and ReLU, each followed by a 2x2 max pool, then a
+ReLU dense layer and the 10-class output.  The flatten before ``dense1``
+is (H, W, C) with the channel fastest.
+"""
+
+IMAGE = (28, 28, 1)
+
+# (name, kind, kernel, c_in, c_out, output map side before pooling, pool)
+LAYERS = (
+    ("conv1", "conv", 5, 1, 32, 28, True),
+    ("conv2", "conv", 5, 32, 64, 14, True),
+    ("dense1", "dense", 0, 7 * 7 * 64, 512, 0, False),
+    ("dense2", "dense", 0, 512, 10, 0, False),
+)
+
+# EMS width groups: (name, channels, producing layer, consuming layer,
+# spatial positions per channel in the consumer's input).  Channels are
+# ranked by the L2 norm of the producer's output slice.
+GROUPS = (
+    ("conv1", 32, "conv1", "conv2", 1),
+    ("conv2", 64, "conv2", "dense1", 49),
+    ("dense1", 512, "dense1", "dense2", 1),
+)
