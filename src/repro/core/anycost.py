@@ -22,6 +22,7 @@ import numpy as np
 from repro.core import aggregation, compression, shrinking
 from repro.core.schedule import Strategy
 from repro.models.registry import Model, build_model, loss_fn
+from repro.telemetry import profiler
 from repro.utils.pytree import tree_sub
 
 PyTree = Any
@@ -174,11 +175,12 @@ class AnycostClient:
         from repro.utils.pytree import tree_size
         n = tree_size(values)          # full-coordinate size
         n_samples = n_steps * self.batch_size
+        bits = float(profiler.read(bits))
         return ClientUpdate(
             values=values, mask=mask, alpha=alpha,
             beta_target=float(strategy.beta),
-            beta_realized=float(bits) / (32.0 * n),
-            bits=float(bits), n_samples=n_samples,
+            beta_realized=bits / (32.0 * n),
+            bits=bits, n_samples=n_samples,
             flops=alpha * w_per_sample * n_samples)
 
     def finish_round_fast(self, alpha: float, trained: PyTree,
@@ -228,10 +230,11 @@ class AnycostClient:
         from repro.utils.pytree import tree_size
         n = tree_size(full_update)
         n_samples = n_steps * self.batch_size
+        bits = float(profiler.read(comp.bits))
         return ClientUpdate(
             values=values, mask=mask, alpha=alpha, beta_target=beta,
-            beta_realized=float(comp.bits) / (32.0 * n),
-            bits=float(comp.bits), n_samples=n_samples,
+            beta_realized=bits / (32.0 * n),
+            bits=bits, n_samples=n_samples,
             flops=alpha * w_per_sample * n_samples)
 
 

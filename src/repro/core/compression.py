@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.telemetry import profiler
 from repro.utils.pytree import flatten_to_vector, tree_size
 
 PyTree = Any
@@ -72,7 +73,7 @@ def kernel_segments(tree: PyTree) -> tuple[np.ndarray, int]:
 def kernel_norms(v: jax.Array, seg_ids: np.ndarray, n_kernels: int
                  ) -> jax.Array:
     """Per-kernel L2 norms of the flat update vector."""
-    sq = jax.ops.segment_sum(jnp.square(v), jnp.asarray(seg_ids),
+    sq = jax.ops.segment_sum(jnp.square(v), profiler.put(seg_ids),
                              num_segments=n_kernels)
     return jnp.sqrt(sq)
 
@@ -103,7 +104,7 @@ def sparsify_mask(v: jax.Array, seg_ids: np.ndarray, n_kernels: int,
     norms = kernel_norms(v, seg_ids, n_kernels)
     thr = sparsify_threshold(norms, rho)
     keep = norms >= thr                       # (K,)
-    return keep[jnp.asarray(seg_ids)].astype(v.dtype)
+    return keep[profiler.put(seg_ids)].astype(v.dtype)
 
 
 # -------------------------------------------------------------- quantization
@@ -317,8 +318,9 @@ class BetaPlanner:
             for L in level_grid:
                 q = prob_quantize(vec, mask, L, key)
                 bits = compressed_bits(q, mask, 65535)
-                beta = float(bits) / (32.0 * n)
-                err = float(jnp.linalg.norm(q.values * mask - vec))
+                beta = float(profiler.read(bits)) / (32.0 * n)
+                err = float(profiler.read(
+                    jnp.linalg.norm(q.values * mask - vec)))
                 records.append((beta, rho, L, err))
         # pareto: for ascending beta keep min-err
         records.sort()

@@ -200,7 +200,6 @@ def run_fl(args):
             rollup = RollupPolicy(device_threshold=args.telemetry_rollup,
                                   seed=args.seed)
         tel = Telemetry(args.telemetry_dir,
-                        jax_profile=args.jax_profile,
                         rollup=rollup,
                         trace_sample=args.trace_sample,
                         trace_seed=args.seed)
@@ -216,7 +215,7 @@ def run_fl(args):
             else DEFAULT_RULES
         tel.health = HealthEngine(rules)
     hist = run_orchestrated(run_cfg, fleet, orch, verbose=True,
-                            telemetry=tel)
+                            telemetry=tel, jax_profile=args.jax_profile)
     # time-to-accuracy: simulated wall-clock at fixed accuracy milestones
     tta = {f"acc>={th:.2f}": hist.time_to_acc(th)
            for th in (0.3, 0.5, 0.7, 0.9) if hist.best_acc >= th}
@@ -412,10 +411,11 @@ def build_parser() -> argparse.ArgumentParser:
                          "trace.jsonl, metrics.jsonl, manifest.json. "
                          "Off by default — disabled telemetry is "
                          "bitwise-invisible to the seeded run")
-    ap.add_argument("--jax-profile", action="store_true",
-                    help="additionally wrap the run in jax.profiler "
-                         "(kernel-level host trace under "
-                         "<telemetry-dir>/jax_profile)")
+    ap.add_argument("--jax-profile", default=None, metavar="DIR",
+                    help="run the round loop under jax.profiler and write "
+                         "its trace (device planes and the fl.* host "
+                         "spans; load in TensorBoard or Perfetto) to DIR. "
+                         "Needs no telemetry session")
     ap.add_argument("--health", action="store_true",
                     help="attach the streaming health engine (needs "
                          "--telemetry-dir): rule-based detectors over "

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.core import shrinking
 from repro.core.anycost import AnycostClient
+from repro.telemetry import profiler
 
 PyTree = Any
 
@@ -111,7 +112,7 @@ class ClientPool:
         # unstack on the host: eager x[i] slices would compile one tiny
         # executable per (leaf shape, index); numpy views are free, and the
         # downstream jit'd decode re-ingests them with identical avals
-        out = jax.device_get(out)
+        out = profiler.read(out)
         return [_tree_index(out, i) for i in range(n)]
 
     # ----------------------------------------------------------- public API
@@ -123,22 +124,24 @@ class ClientPool:
         shrunk params so the caller's slices are reused instead of
         re-shrinking per width bucket."""
         out: list = [None] * len(jobs)
-        for (alpha, n_steps, _), idxs in self._groups(jobs).items():
-            sub = (subs or {}).get(alpha)
-            if sub is None:
-                sub = shrinking.shrink(sorted_global, alpha,
-                                       self.client.spec)
-            for j, trained in zip(idxs, self._run_group(
-                    alpha, n_steps, idxs, jobs, sub, shared=True)):
-                out[j] = trained
+        with profiler.span("fl.local_train", n_clients=len(jobs)):
+            for (alpha, n_steps, _), idxs in self._groups(jobs).items():
+                sub = (subs or {}).get(alpha)
+                if sub is None:
+                    sub = shrinking.shrink(sorted_global, alpha,
+                                           self.client.spec)
+                for j, trained in zip(idxs, self._run_group(
+                        alpha, n_steps, idxs, jobs, sub, shared=True)):
+                    out[j] = trained
         return out
 
     def train_stacked(self, jobs: list[TrainJob]) -> list[PyTree]:
         """Train jobs that carry their own (per-version) sub params."""
         out: list = [None] * len(jobs)
-        for (alpha, n_steps, _), idxs in self._groups(jobs).items():
-            single = jobs[idxs[0]].sub_params
-            for j, trained in zip(idxs, self._run_group(
-                    alpha, n_steps, idxs, jobs, single, shared=False)):
-                out[j] = trained
+        with profiler.span("fl.local_train", n_clients=len(jobs)):
+            for (alpha, n_steps, _), idxs in self._groups(jobs).items():
+                single = jobs[idxs[0]].sub_params
+                for j, trained in zip(idxs, self._run_group(
+                        alpha, n_steps, idxs, jobs, single, shared=False)):
+                    out[j] = trained
         return out

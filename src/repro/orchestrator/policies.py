@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import aggregation
+from repro.telemetry import profiler
 from repro.train.baselines import fedhq_weights
 
 POLICIES = ("sync", "semisync", "fedbuff")
@@ -133,8 +134,8 @@ def unnormalized_weight(method: str, use_aio: bool, update,
     aggregate up to float rounding.
     """
     if method == "anycostfl" and use_aio:
-        d = float(aggregation.divergence_factor(
-            update.alpha, max(update.beta_target, 1e-6)))
+        d = float(profiler.read(aggregation.divergence_factor(
+            update.alpha, max(update.beta_target, 1e-6))))
         return 1.0 / max(d * d, 1e-12)
     if method == "fedhq":
         L = int(fedhq_level)

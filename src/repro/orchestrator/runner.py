@@ -79,7 +79,7 @@ from repro.orchestrator.policies import (STALE_REQUEUE, OrchestratorConfig,
                                          make_policy, staleness_scales,
                                          unnormalized_weight)
 from repro.sysmodel.population import FleetConfig, make_fleet
-from repro.telemetry import NULL_TELEMETRY, MetricsRegistry, profile_trace
+from repro.telemetry import NULL_TELEMETRY, MetricsRegistry, profiler
 from repro.topology.codec import decode_partial, encode_partial
 from repro.topology.edge import (CodecErrorFeedback, EdgeAggregator,
                                  cloud_merge, finalize_apply)
@@ -189,6 +189,8 @@ class Simulation:
         self.pool = ClientPool(self.client)
         self._agg_fast = None
         self._shrink_cache: dict = {}
+        # the round (fedbuff: the merge) in progress, for the fl.* spans
+        self.round_idx = 0
 
         # ---- fleet-dynamics control plane.  Selection randomness lives in
         # its own generator so who-trains-when ablations never perturb the
@@ -311,7 +313,9 @@ class Simulation:
     # ------------------------------------------------------------ round body
 
     def sort_params(self, params: PyTree) -> PyTree:
-        if self.run_cfg.use_ems:
+        with profiler.span("fl.sort", round=self.round_idx):
+            if not self.run_cfg.use_ems:
+                return shrinking._deepcopy_dicts(params)
             if self.codec_ef is None:
                 return self.server.sort(params)
             # EF residuals live in the sorted coordinate frame; capture
@@ -321,9 +325,8 @@ class Simulation:
             sorted_p, perms = shrinking.sort_channels(
                 params, self.spec, return_perms=True)
             self._ef_frame = tuple(
-                tuple(np.asarray(p).tolist()) for p in perms)
+                tuple(p.tolist()) for p in profiler.read(perms))
             return sorted_p
-        return shrinking._deepcopy_dicts(params)
 
     def ensure_planner(self, sorted_params: PyTree) -> None:
         """Fit the server-side beta planner on a probe update (§III-C.3)."""
@@ -333,8 +336,8 @@ class Simulation:
             self.key, k1 = jax.random.split(self.key)
             probe_idx = self.rng.permutation(rc.n_train)[:16]
             probe_batches = {
-                "images": jnp.asarray(self.train.x[probe_idx][None]),
-                "labels": jnp.asarray(self.train.y[probe_idx][None])}
+                "images": profiler.put(self.train.x[probe_idx][None]),
+                "labels": profiler.put(self.train.y[probe_idx][None])}
             trained = self.client._local_steps(1.0, 1)(sorted_params,
                                                        probe_batches)
             probe_update = tree_sub(sorted_params, trained)
@@ -346,22 +349,24 @@ class Simulation:
         old loop's order). Returns None when no (alpha, beta, f) satisfies
         the budgets (the device sits this dispatch out)."""
         rc = self.run_cfg
-        if rc.method == "anycostfl":
-            strat = schedule.solve(env)
-            if not strat.feasible:
-                return None
-            if not rc.use_ems:
-                strat = dataclasses.replace(strat, alpha=1.0)
-            if not rc.use_fgc:
-                strat = dataclasses.replace(strat, beta=1.0)
-            alpha = bucket_alpha(strat.alpha, rc.alpha_buckets)
-        else:
-            strat = self.baseline.strategy(env, tier=int(self.tiers[i]))
-            alpha = bucket_alpha(strat.alpha, rc.alpha_buckets) \
-                if rc.method == "heterofl" else 1.0
+        with profiler.span("fl.schedule", round=self.round_idx, client=i):
+            if rc.method == "anycostfl":
+                strat = schedule.solve(env)
+                if not strat.feasible:
+                    return None
+                if not rc.use_ems:
+                    strat = dataclasses.replace(strat, alpha=1.0)
+                if not rc.use_fgc:
+                    strat = dataclasses.replace(strat, beta=1.0)
+                alpha = bucket_alpha(strat.alpha, rc.alpha_buckets)
+            else:
+                strat = self.baseline.strategy(env, tier=int(self.tiers[i]))
+                alpha = bucket_alpha(strat.alpha, rc.alpha_buckets) \
+                    if rc.method == "heterofl" else 1.0
         self.key, k1, k2 = jax.random.split(self.key, 3)
-        batches = _device_batches(self.rng, self.train.x, self.train.y,
-                                  self.parts[i], rc.batch_size, rc.tau)
+        with profiler.span("fl.batches", round=self.round_idx, client=i):
+            batches = _device_batches(self.rng, self.train.x, self.train.y,
+                                      self.parts[i], rc.batch_size, rc.tau)
         n_steps = int(jax.tree_util.tree_leaves(batches)[0].shape[0])
         return PendingUpdate(client_id=i, env=env, strat=strat, alpha=alpha,
                              batches=batches, key=k2, n_steps=n_steps,
@@ -378,54 +383,58 @@ class Simulation:
         (Eq. 6-9). The default path keeps float-op order identical to the
         old loop; ``fast=True`` routes through the jit'd finish pipeline
         (equivalent up to fusion) for high-event-rate policies."""
-        rc = self.run_cfg
-        env, strat = p.env, p.strat
-        if rc.method == "anycostfl":
-            if fast:
-                if sub is None:
-                    sub = shrinking.shrink(sorted_params, p.alpha, self.spec)
-                upd = self.client.finish_round_fast(
-                    p.alpha, trained, strat, p.n_steps, p.key, sub=sub,
-                    planner=self.planner if rc.use_fgc else None,
-                    w_per_sample=self.W)
+        with profiler.span("fl.finish", round=self.round_idx,
+                           client=p.client_id):
+            rc = self.run_cfg
+            env, strat = p.env, p.strat
+            if rc.method == "anycostfl":
+                if fast:
+                    if sub is None:
+                        sub = shrinking.shrink(sorted_params, p.alpha,
+                                               self.spec)
+                    upd = self.client.finish_round_fast(
+                        p.alpha, trained, strat, p.n_steps, p.key, sub=sub,
+                        planner=self.planner if rc.use_fgc else None,
+                        w_per_sample=self.W)
+                else:
+                    upd = self.client.finish_round(
+                        sorted_params, p.alpha, trained, strat, p.n_steps,
+                        p.key, planner=self.planner if rc.use_fgc else None,
+                        w_per_sample=self.W, sub=sub)
+                if not rc.use_fgc:
+                    # transmit the raw (width-masked) update
+                    upd = dataclasses.replace(
+                        upd, bits=32.0 * strat.alpha * self._n_params,
+                        beta_realized=1.0)
             else:
-                upd = self.client.finish_round(
-                    sorted_params, p.alpha, trained, strat, p.n_steps, p.key,
-                    planner=self.planner if rc.use_fgc else None,
-                    w_per_sample=self.W, sub=sub)
-            if not rc.use_fgc:
-                # transmit the raw (width-masked) update
-                upd = dataclasses.replace(
-                    upd, bits=32.0 * strat.alpha * self._n_params,
-                    beta_realized=1.0)
-        else:
-            sub = shrinking.shrink(sorted_params, p.alpha, self.spec)
-            update_sub = tree_sub(sub, trained)
-            full_update, wmask = shrinking.expand_update(
-                update_sub, sorted_params, p.alpha, self.spec)
-            comp = self.baseline.compress(full_update, env, p.key)
-            mask = jax.tree.map(lambda a, b: a * b, wmask, comp.mask)
-            vals = jax.tree.map(lambda v, m: v * m, comp.values, mask)
-            n_samp = p.n_steps * rc.batch_size
-            upd = ClientUpdate(
-                values=vals, mask=mask, alpha=p.alpha,
-                beta_target=strat.beta,
-                beta_realized=float(comp.bits) / self.S_bits,
-                bits=float(comp.bits), n_samples=n_samp,
-                flops=p.alpha * self.W * n_samp)
-            if rc.method == "fedhq":
-                p.fedhq_level = self.baseline.fedhq_levels(env)
-        p.update = upd
-        # realized costs (Eq. 6-9) with the *realized* wire size
-        t_com = upd.bits / env.rate
-        e_com = t_com * env.P_com
-        t_cmp = upd.alpha * env.tau * env.D * env.W / strat.freq
-        e_cmp = env.eps_hw * strat.freq ** 2 * upd.alpha \
-            * env.tau * env.D * env.W
-        p.t_com, p.t_cmp = t_com, t_cmp
-        p.e_cmp, p.e_com = e_cmp, e_com
-        p.energy = e_cmp + e_com
-        return p
+                sub = shrinking.shrink(sorted_params, p.alpha, self.spec)
+                update_sub = tree_sub(sub, trained)
+                full_update, wmask = shrinking.expand_update(
+                    update_sub, sorted_params, p.alpha, self.spec)
+                comp = self.baseline.compress(full_update, env, p.key)
+                mask = jax.tree.map(lambda a, b: a * b, wmask, comp.mask)
+                vals = jax.tree.map(lambda v, m: v * m, comp.values, mask)
+                n_samp = p.n_steps * rc.batch_size
+                bits = float(profiler.read(comp.bits))
+                upd = ClientUpdate(
+                    values=vals, mask=mask, alpha=p.alpha,
+                    beta_target=strat.beta,
+                    beta_realized=bits / self.S_bits,
+                    bits=bits, n_samples=n_samp,
+                    flops=p.alpha * self.W * n_samp)
+                if rc.method == "fedhq":
+                    p.fedhq_level = self.baseline.fedhq_levels(env)
+            p.update = upd
+            # realized costs (Eq. 6-9) with the *realized* wire size
+            t_com = upd.bits / env.rate
+            e_com = t_com * env.P_com
+            t_cmp = upd.alpha * env.tau * env.D * env.W / strat.freq
+            e_cmp = env.eps_hw * strat.freq ** 2 * upd.alpha \
+                * env.tau * env.D * env.W
+            p.t_com, p.t_cmp = t_com, t_cmp
+            p.e_cmp, p.e_com = e_cmp, e_com
+            p.energy = e_cmp + e_com
+            return p
 
     def shrink_fast(self, sorted_params: PyTree, alpha: float) -> PyTree:
         """jit'd EMS slice (one compile per width bucket) for hot paths."""
@@ -437,28 +446,31 @@ class Simulation:
 
     def aggregate(self, sorted_params: PyTree, accepted: list[PendingUpdate],
                   weights: jax.Array, *, fast: bool = False) -> PyTree:
-        if not fast:
-            return self.server.aggregate(sorted_params,
-                                         [p.update for p in accepted],
-                                         weights=weights)
-        # jit'd wrapper over the canonical Eq.-5 merge + server step (jit
-        # retraces per update count — the input lists are pytrees)
-        if self._agg_fast is None:
-            server = self.server
+        with profiler.span("fl.aggregate", round=self.round_idx,
+                           n_clients=len(accepted)):
+            if not fast:
+                return self.server.aggregate(sorted_params,
+                                             [p.update for p in accepted],
+                                             weights=weights)
+            # jit'd wrapper over the canonical Eq.-5 merge + server step
+            # (jit retraces per update count — the input lists are pytrees)
+            if self._agg_fast is None:
+                server = self.server
 
-            @jax.jit
-            def agg(params, values, masks, w):
-                return server.apply_update(
-                    params, aggregation.aio_aggregate(values, masks, w))
+                @jax.jit
+                def agg(params, values, masks, w):
+                    return server.apply_update(
+                        params, aggregation.aio_aggregate(values, masks, w))
 
-            self._agg_fast = agg
-        return self._agg_fast(sorted_params,
-                              [p.update.values for p in accepted],
-                              [p.update.mask for p in accepted], weights)
+                self._agg_fast = agg
+            return self._agg_fast(sorted_params,
+                                  [p.update.values for p in accepted],
+                                  [p.update.mask for p in accepted], weights)
 
     def evaluate(self, params: PyTree) -> tuple[float, float]:
-        acc, loss = self.ev(params)
-        return float(acc), float(loss)
+        with profiler.span("fl.eval", round=self.round_idx):
+            acc, loss = self.ev(params)
+            return float(profiler.read(acc)), float(profiler.read(loss))
 
     # --------------------------------------------------- hierarchical glue
 
@@ -726,249 +738,260 @@ def _run_round_based(sim: Simulation, policy, orch: OrchestratorConfig,
     t_wall = 0.0
 
     for t in range(rc.rounds):
-        # round-boundary handover: re-home mobile devices to their
-        # serving cell *before* dispatch, so this round's channels,
-        # selection, and edge merges all see the new binding.  One
-        # HANDOVER event per move lands on the recorded timeline.
-        n_handover = 0
-        if sim.handover is not None:
-            new_cells, moves = sim.handover.reassign(
-                sim.fleet.positions(t_wall), sim.fleet.cells)
-            for i, old, new in moves:
-                queue.push(t_wall, ev_mod.HANDOVER, i, (old, new))
-                if tel.enabled:
-                    tel.instant(f"device/{i}", "HANDOVER", t_wall,
-                                round=t, src_cell=old, dst_cell=new)
-                    tel.counter("mobility.handovers", 1.0, device=i,
-                                round=t)
-            for _ in moves:
-                queue.pop()
-            sim.fleet.cells = new_cells
-            n_handover = len(moves)
-        envs = sim.fleet.round_envs(sim.rng, sim.W, sim.S_bits, t=t_wall)
-        sorted_params = sim.sort_params(params)
-        sim.ensure_planner(sorted_params)
+        sim.round_idx = t
+        with profiler.span("fl.round", round=t) as round_span:
+            # round-boundary handover: re-home mobile devices to their
+            # serving cell *before* dispatch, so this round's channels,
+            # selection, and edge merges all see the new binding.  One
+            # HANDOVER event per move lands on the recorded timeline.
+            n_handover = 0
+            if sim.handover is not None:
+                new_cells, moves = sim.handover.reassign(
+                    sim.fleet.positions(t_wall), sim.fleet.cells)
+                for i, old, new in moves:
+                    queue.push(t_wall, ev_mod.HANDOVER, i, (old, new))
+                    if tel.enabled:
+                        tel.instant(f"device/{i}", "HANDOVER", t_wall,
+                                    round=t, src_cell=old, dst_cell=new)
+                        tel.counter("mobility.handovers", 1.0, device=i,
+                                    round=t)
+                for _ in moves:
+                    queue.pop()
+                sim.fleet.cells = new_cells
+                n_handover = len(moves)
+            with profiler.span("fl.channels", round=t):
+                envs = sim.fleet.round_envs(sim.rng, sim.W, sim.S_bits,
+                                            t=t_wall)
+            sorted_params = sim.sort_params(params)
+            sim.ensure_planner(sorted_params)
 
-        selected, envs_eff, n_unavail, headroom = sim.gate_round(t_wall,
-                                                                 envs)
-        t_max_eff = sim.effective_T_max(t_wall)
-        occupancy = int(np.bincount(sim.fleet.cells).max()) \
-            if sim.fleet.cells is not None else 0
-        pendings = [p for p in (sim.prepare(i, envs_eff[i])
-                                for i in selected)
-                    if p is not None]
-        for p in pendings:
-            sim.dispatch_log.append((t_wall, p.client_id,
-                                     headroom[p.client_id]))
-        if tel.enabled:
-            tel.counter("fleet.unavailable", float(n_unavail), round=t)
-            tel.counter("fleet.selected", float(len(selected)), round=t)
-            tel.counter("fleet.infeasible",
-                        float(len(selected) - len(pendings)), round=t)
+            selected, envs_eff, n_unavail, headroom = sim.gate_round(t_wall,
+                                                                     envs)
+            t_max_eff = sim.effective_T_max(t_wall)
+            occupancy = int(np.bincount(sim.fleet.cells).max()) \
+                if sim.fleet.cells is not None else 0
+            pendings = [p for p in (sim.prepare(i, envs_eff[i])
+                                    for i in selected)
+                        if p is not None]
+            for p in pendings:
+                sim.dispatch_log.append((t_wall, p.client_id,
+                                         headroom[p.client_id]))
+            if tel.enabled:
+                tel.counter("fleet.unavailable", float(n_unavail), round=t)
+                tel.counter("fleet.selected", float(len(selected)), round=t)
+                tel.counter("fleet.infeasible",
+                            float(len(selected) - len(pendings)), round=t)
 
-        # mid-round churn: a device that leaves the cell before its
-        # *planned* T_cmp + T_com elapses aborts — its update never
-        # arrives, training is skipped, and the compute/energy burned up
-        # to the departure is charged (pro-rated over the planned flight)
-        live, aborted = [], []
-        for p in pendings:
-            t_off = sim.fleet.next_departure(p.client_id, t_wall)
-            planned = p.strat.T_cmp + p.strat.T_com
-            if t_off < t_wall + planned:
-                p.dispatched_at = t_wall
-                p.completes_at = t_off
-                frac = min(1.0, (t_off - t_wall) / planned) \
-                    if planned > 0 else 1.0
-                p.energy = frac * (p.strat.E_cmp + p.strat.E_com)
-                p.e_cmp = frac * p.strat.E_cmp
-                p.e_com = frac * p.strat.E_com
-                aborted.append(p)
+            # mid-round churn: a device that leaves the cell before its
+            # *planned* T_cmp + T_com elapses aborts — its update never
+            # arrives, training is skipped, and the compute/energy burned up
+            # to the departure is charged (pro-rated over the planned flight)
+            live, aborted = [], []
+            for p in pendings:
+                t_off = sim.fleet.next_departure(p.client_id, t_wall)
+                planned = p.strat.T_cmp + p.strat.T_com
+                if t_off < t_wall + planned:
+                    p.dispatched_at = t_wall
+                    p.completes_at = t_off
+                    frac = min(1.0, (t_off - t_wall) / planned) \
+                        if planned > 0 else 1.0
+                    p.energy = frac * (p.strat.E_cmp + p.strat.E_com)
+                    p.e_cmp = frac * p.strat.E_cmp
+                    p.e_com = frac * p.strat.E_com
+                    aborted.append(p)
+                else:
+                    live.append(p)
+            round_span.set_metadata(n_clients=len(live))
+
+            subs: dict = {}
+            if use_pool and rc.method == "anycostfl":
+                for p in live:
+                    if p.alpha not in subs:
+                        subs[p.alpha] = sim.shrink_fast(sorted_params, p.alpha)
+            if use_pool:
+                trained = sim.pool.train_shared(
+                    sorted_params,
+                    [TrainJob(p.client_id, p.alpha, p.batches)
+                     for p in live], subs)
             else:
-                live.append(p)
+                trained = []
+                for p in live:
+                    with profiler.span("fl.local_train", round=t,
+                                       client=p.client_id):
+                        trained.append(sim.train_one(p, sorted_params))
 
-        subs: dict = {}
-        if use_pool and rc.method == "anycostfl":
-            for p in live:
-                if p.alpha not in subs:
-                    subs[p.alpha] = sim.shrink_fast(sorted_params, p.alpha)
-        if use_pool:
-            trained = sim.pool.train_shared(
-                sorted_params,
-                [TrainJob(p.client_id, p.alpha, p.batches)
-                 for p in live], subs)
-        else:
-            trained = [sim.train_one(p, sorted_params) for p in live]
-
-        en, fl, cb = 0.0, 0.0, 0.0
-        en_cmp = en_com = 0.0
-        for p, tr in zip(live, trained):
-            sim.materialize(p, tr, sorted_params, fast=use_pool,
-                            sub=subs.get(p.alpha))
-            p.dispatched_at = t_wall
-            p.completes_at = t_wall + p.duration
-            # dispatch->arrival flight time goes to the always-live
-            # registry (like the round.* gauges), so p95 dispatch
-            # latency is queryable/gateable without a telemetry session
-            # repro: ignore[unguarded-telemetry] — always-live by design
-            sim.registry.observe("dispatch.latency_s", p.duration,
-                                 device=p.client_id, cell=p.cell,
-                                 round=t)
-            queue.push(p.completes_at, ev_mod.COMPLETE, p.client_id, p)
-            en += p.energy
-            en_cmp += p.e_cmp
-            en_com += p.e_com
-            fl += p.update.flops
-            cb += p.update.bits
-            if tel.enabled:
-                sub_s = subs.get(p.alpha)
-                if sub_s is None:
-                    sub_s = shrinking.shrink(sorted_params, p.alpha,
-                                             sim.spec)
-                sim.learn.record_device(
-                    tel, p.client_id, t,
-                    sim.learn.device_stats(p.alpha, sub_s, tr,
-                                           p.update.values,
-                                           p.update.mask))
-                tel.span(f"device/{p.client_id}", "train", t_wall,
-                         t_wall + p.t_cmp, round=t, cell=p.cell,
-                         alpha=p.update.alpha, energy_j=p.e_cmp,
-                         flops=p.update.flops)
-                tel.span(f"device/{p.client_id}", "uplink",
-                         t_wall + p.t_cmp, t_wall + p.duration, round=t,
-                         cell=p.cell, bits=p.update.bits,
-                         beta=p.update.beta_realized, energy_j=p.e_com)
-                tel.counter("cost.energy_j", p.e_cmp,
-                            device=p.client_id, cell=p.cell,
-                            phase="train", round=t)
-                tel.counter("cost.energy_j", p.e_com,
-                            device=p.client_id, cell=p.cell,
-                            phase="uplink", round=t)
-                tel.counter("cost.comm_bits", p.update.bits,
-                            device=p.client_id, cell=p.cell,
-                            phase="uplink", round=t)
-        for p in aborted:
-            queue.push(p.completes_at, ev_mod.CHURN, p.client_id, p)
-            en += p.energy
-            en_cmp += p.e_cmp
-            en_com += p.e_com
-            if tel.enabled:
-                tel.instant(f"device/{p.client_id}", "CHURN",
-                            p.completes_at, round=t, cell=p.cell)
-                tel.counter("cost.energy_j", p.e_cmp,
-                            device=p.client_id, cell=p.cell,
-                            phase="train", round=t)
-                tel.counter("cost.energy_j", p.e_com,
-                            device=p.client_id, cell=p.cell,
-                            phase="uplink", round=t)
-        for _ in range(len(live) + len(aborted)):  # record arrival order
-            queue.pop()
-
-        if not live:               # every device faded out this round
+            en, fl, cb = 0.0, 0.0, 0.0
+            en_cmp = en_com = 0.0
+            for p, tr in zip(live, trained):
+                sim.materialize(p, tr, sorted_params, fast=use_pool,
+                                sub=subs.get(p.alpha))
+                p.dispatched_at = t_wall
+                p.completes_at = t_wall + p.duration
+                # dispatch->arrival flight time goes to the always-live
+                # registry (like the round.* gauges), so p95 dispatch
+                # latency is queryable/gateable without a telemetry session
+                # repro: ignore[unguarded-telemetry] — always-live by design
+                sim.registry.observe("dispatch.latency_s", p.duration,
+                                     device=p.client_id, cell=p.cell,
+                                     round=t)
+                queue.push(p.completes_at, ev_mod.COMPLETE, p.client_id, p)
+                en += p.energy
+                en_cmp += p.e_cmp
+                en_com += p.e_com
+                fl += p.update.flops
+                cb += p.update.bits
+                if tel.enabled:
+                    sub_s = subs.get(p.alpha)
+                    if sub_s is None:
+                        sub_s = shrinking.shrink(sorted_params, p.alpha,
+                                                 sim.spec)
+                    sim.learn.record_device(
+                        tel, p.client_id, t,
+                        sim.learn.device_stats(p.alpha, sub_s, tr,
+                                               p.update.values,
+                                               p.update.mask))
+                    tel.span(f"device/{p.client_id}", "train", t_wall,
+                             t_wall + p.t_cmp, round=t, cell=p.cell,
+                             alpha=p.update.alpha, energy_j=p.e_cmp,
+                             flops=p.update.flops)
+                    tel.span(f"device/{p.client_id}", "uplink",
+                             t_wall + p.t_cmp, t_wall + p.duration, round=t,
+                             cell=p.cell, bits=p.update.bits,
+                             beta=p.update.beta_realized, energy_j=p.e_com)
+                    tel.counter("cost.energy_j", p.e_cmp,
+                                device=p.client_id, cell=p.cell,
+                                phase="train", round=t)
+                    tel.counter("cost.energy_j", p.e_com,
+                                device=p.client_id, cell=p.cell,
+                                phase="uplink", round=t)
+                    tel.counter("cost.comm_bits", p.update.bits,
+                                device=p.client_id, cell=p.cell,
+                                phase="uplink", round=t)
             for p in aborted:
-                sim.fleet.debit(p.client_id, p.energy, p.completes_at)
-            hist.log_round(
-                t, latency_s=0.0, energy_j=en, flops=0.0,
-                comm_bits=0.0, mean_alpha=0.0, mean_beta=0.0,
-                mean_gain=0.0, t_wall=t_wall, n_unavailable=n_unavail,
-                n_aborted=len(aborted),
+                queue.push(p.completes_at, ev_mod.CHURN, p.client_id, p)
+                en += p.energy
+                en_cmp += p.e_cmp
+                en_com += p.e_com
+                if tel.enabled:
+                    tel.instant(f"device/{p.client_id}", "CHURN",
+                                p.completes_at, round=t, cell=p.cell)
+                    tel.counter("cost.energy_j", p.e_cmp,
+                                device=p.client_id, cell=p.cell,
+                                phase="train", round=t)
+                    tel.counter("cost.energy_j", p.e_com,
+                                device=p.client_id, cell=p.cell,
+                                phase="uplink", round=t)
+            for _ in range(len(live) + len(aborted)):  # record arrival order
+                queue.pop()
+
+            if not live:               # every device faded out this round
+                for p in aborted:
+                    sim.fleet.debit(p.client_id, p.energy, p.completes_at)
+                hist.log_round(
+                    t, latency_s=0.0, energy_j=en, flops=0.0,
+                    comm_bits=0.0, mean_alpha=0.0, mean_beta=0.0,
+                    mean_gain=0.0, t_wall=t_wall, n_unavailable=n_unavail,
+                    n_aborted=len(aborted),
+                    mean_soc=(sim.fleet.battery.mean_soc_frac(t_wall)
+                              if sim.fleet.battery is not None else 1.0),
+                    n_handovers=n_handover, max_cell_occupancy=occupancy,
+                    t_max_effective=t_max_eff,
+                    energy_train_j=en_cmp, energy_uplink_j=en_com)
+                if sim.fleet_dynamic:
+                    # idle server deadline: let traces/batteries evolve so the
+                    # fleet can come back (a static fleet must not drift)
+                    t_wall += sim.fleet_cfg.T_max
+                continue
+
+            bh_bits, n_cells_rep, e_ship = 0.0, 0, 0.0
+            agg_delta = None
+            if sim.topo is not None:
+                with profiler.span("fl.aggregate", round=t,
+                                   n_clients=len(live)):
+                    (accepted, new_params, lat, e_ship, bh_bits, n_cells_rep,
+                     lat_parts) = _hier_round_merge(
+                         sim, policy, live, aborted, sorted_params, queue,
+                         t_wall, round_idx=t)
+                en += e_ship
+                t_wall += lat
+                for p in live + aborted:
+                    sim.fleet.debit(p.client_id, p.energy, t_wall)
+                if new_params is not None:
+                    params = new_params
+                    if tel.enabled:
+                        agg_delta = tree_sub(sorted_params, new_params)
+            else:
+                accepted, scales, lat = policy.accept(live, 0.0)
+                if aborted:
+                    # the server learns of a dropout at the departure moment,
+                    # but never waits past its own deadline barrier (semisync)
+                    barrier = getattr(policy, "deadline", math.inf)
+                    lat = max(lat, min(barrier,
+                                       max(p.completes_at - t_wall
+                                           for p in aborted)))
+                # critical-path split: compute until the slowest accepted
+                # client's T_cmp elapses, uplink/barrier wait for the rest
+                lt = min(lat, max((p.t_cmp for p in accepted), default=0.0))
+                lat_parts = (lt, lat - lt, 0.0)
+                t_wall += lat
+                for p in live + aborted:
+                    sim.fleet.debit(p.client_id, p.energy, t_wall)
+                if accepted:
+                    fedhq_L = [p.fedhq_level for p in accepted] \
+                        if rc.method == "fedhq" else []
+                    w = base_weights(rc.method, rc.use_aio,
+                                     [p.update for p in accepted], fedhq_L)
+                    w = apply_scales(w, scales)
+                    params = sim.aggregate(sorted_params, accepted, w,
+                                           fast=use_pool)
+                    if tel.enabled:
+                        agg_delta = tree_sub(sorted_params, params)
+                        for p, wv in zip(accepted, np.asarray(w)):
+                            sim.learn.note_contribution(p.client_id,
+                                                        float(wv))
+
+            log = hist.log_round(
+                t, latency_s=lat, energy_j=en, flops=fl, comm_bits=cb,
+                mean_alpha=float(np.mean([p.update.alpha for p in live])),
+                mean_beta=float(np.mean([p.update.beta_realized
+                                         for p in live])),
+                mean_gain=float(np.mean([p.strat.gain for p in live])),
+                t_wall=t_wall, n_clients=len(accepted),
+                n_dropped=len(live) - len(accepted),
+                n_unavailable=n_unavail, n_aborted=len(aborted),
                 mean_soc=(sim.fleet.battery.mean_soc_frac(t_wall)
                           if sim.fleet.battery is not None else 1.0),
+                n_cells_reporting=n_cells_rep, backhaul_bits=bh_bits,
                 n_handovers=n_handover, max_cell_occupancy=occupancy,
                 t_max_effective=t_max_eff,
-                energy_train_j=en_cmp, energy_uplink_j=en_com)
-            if sim.fleet_dynamic:
-                # idle server deadline: let traces/batteries evolve so the
-                # fleet can come back (a static fleet must not drift)
-                t_wall += sim.fleet_cfg.T_max
-            continue
-
-        bh_bits, n_cells_rep, e_ship = 0.0, 0, 0.0
-        agg_delta = None
-        if sim.topo is not None:
-            (accepted, new_params, lat, e_ship, bh_bits, n_cells_rep,
-             lat_parts) = _hier_round_merge(sim, policy, live, aborted,
-                                            sorted_params, queue, t_wall,
-                                            round_idx=t)
-            en += e_ship
-            t_wall += lat
-            for p in live + aborted:
-                sim.fleet.debit(p.client_id, p.energy, t_wall)
-            if new_params is not None:
-                params = new_params
-                if tel.enabled:
-                    agg_delta = tree_sub(sorted_params, new_params)
-        else:
-            accepted, scales, lat = policy.accept(live, 0.0)
-            if aborted:
-                # the server learns of a dropout at the departure moment,
-                # but never waits past its own deadline barrier (semisync)
-                barrier = getattr(policy, "deadline", math.inf)
-                lat = max(lat, min(barrier,
-                                   max(p.completes_at - t_wall
-                                       for p in aborted)))
-            # critical-path split: compute until the slowest accepted
-            # client's T_cmp elapses, uplink/barrier wait for the rest
-            lt = min(lat, max((p.t_cmp for p in accepted), default=0.0))
-            lat_parts = (lt, lat - lt, 0.0)
-            t_wall += lat
-            for p in live + aborted:
-                sim.fleet.debit(p.client_id, p.energy, t_wall)
-            if accepted:
-                fedhq_L = [p.fedhq_level for p in accepted] \
-                    if rc.method == "fedhq" else []
-                w = base_weights(rc.method, rc.use_aio,
-                                 [p.update for p in accepted], fedhq_L)
-                w = apply_scales(w, scales)
-                params = sim.aggregate(sorted_params, accepted, w,
-                                       fast=use_pool)
-                if tel.enabled:
-                    agg_delta = tree_sub(sorted_params, params)
-                    for p, wv in zip(accepted, np.asarray(w)):
-                        sim.learn.note_contribution(p.client_id,
-                                                    float(wv))
-
-        log = hist.log_round(
-            t, latency_s=lat, energy_j=en, flops=fl, comm_bits=cb,
-            mean_alpha=float(np.mean([p.update.alpha for p in live])),
-            mean_beta=float(np.mean([p.update.beta_realized
-                                     for p in live])),
-            mean_gain=float(np.mean([p.strat.gain for p in live])),
-            t_wall=t_wall, n_clients=len(accepted),
-            n_dropped=len(live) - len(accepted),
-            n_unavailable=n_unavail, n_aborted=len(aborted),
-            mean_soc=(sim.fleet.battery.mean_soc_frac(t_wall)
-                      if sim.fleet.battery is not None else 1.0),
-            n_cells_reporting=n_cells_rep, backhaul_bits=bh_bits,
-            n_handovers=n_handover, max_cell_occupancy=occupancy,
-            t_max_effective=t_max_eff,
-            energy_train_j=en_cmp, energy_uplink_j=en_com,
-            energy_backhaul_j=e_ship,
-            latency_train_s=lat_parts[0],
-            latency_uplink_s=lat_parts[1],
-            latency_backhaul_s=lat_parts[2])
-        if tel.enabled:
-            if agg_delta is not None:
-                for p in accepted:
-                    sim.learn.record_alignment(tel, p.client_id, t,
-                                               p.update.values, agg_delta)
-            sim.learn.record_round(tel, t, agg_delta)
-            tel.span("server", "round", t_wall - lat, t_wall, round=t,
-                     n_clients=len(accepted), n_cells=n_cells_rep,
-                     energy_j=en)
-            if tel.health is not None:
-                tel.health.evaluate(t, t_wall, sim.registry, tel)
-        if t % rc.eval_every == 0 or t == rc.rounds - 1:
-            acc, loss = sim.evaluate(params)
-            hist.log_eval(log, acc, loss)
-            if verbose:
-                print(f"[{rc.method}/{policy.name}] round {t:3d} "
-                      f"acc={acc:.3f} loss={loss:.3f} lat={lat:.2f}s "
-                      f"E={en:.2f}J t={t_wall:.1f}s "
-                      f"alpha={log.mean_alpha:.2f} "
-                      f"beta={log.mean_beta:.4f}")
-        if orch.max_wallclock_s is not None \
-                and t_wall >= orch.max_wallclock_s:
-            break
+                energy_train_j=en_cmp, energy_uplink_j=en_com,
+                energy_backhaul_j=e_ship,
+                latency_train_s=lat_parts[0],
+                latency_uplink_s=lat_parts[1],
+                latency_backhaul_s=lat_parts[2])
+            if tel.enabled:
+                if agg_delta is not None:
+                    for p in accepted:
+                        sim.learn.record_alignment(tel, p.client_id, t,
+                                                   p.update.values, agg_delta)
+                sim.learn.record_round(tel, t, agg_delta)
+                tel.span("server", "round", t_wall - lat, t_wall, round=t,
+                         n_clients=len(accepted), n_cells=n_cells_rep,
+                         energy_j=en)
+                if tel.health is not None:
+                    tel.health.evaluate(t, t_wall, sim.registry, tel)
+            if t % rc.eval_every == 0 or t == rc.rounds - 1:
+                acc, loss = sim.evaluate(params)
+                hist.log_eval(log, acc, loss)
+                if verbose:
+                    print(f"[{rc.method}/{policy.name}] round {t:3d} "
+                          f"acc={acc:.3f} loss={loss:.3f} lat={lat:.2f}s "
+                          f"E={en:.2f}J t={t_wall:.1f}s "
+                          f"alpha={log.mean_alpha:.2f} "
+                          f"beta={log.mean_beta:.4f}")
+            if orch.max_wallclock_s is not None \
+                    and t_wall >= orch.max_wallclock_s:
+                break
     hist.trace = queue.trace_signature()
     hist.dispatch_log = sim.dispatch_log
     hist.params = params
@@ -1110,8 +1133,9 @@ def _run_fedbuff(sim: Simulation, policy, orch: OrchestratorConfig,
                                  else p.env.E_max))
         enqueue_flight(q, now)
 
-    for i, env in enumerate(sim.fleet.round_envs(sim.rng, sim.W,
-                                                 sim.S_bits)):
+    with profiler.span("fl.channels", round=0):
+        envs = sim.fleet.round_envs(sim.rng, sim.W, sim.S_bits)
+    for i, env in enumerate(envs):
         if cap is not None and len(inflight_version) >= cap:
             waiting.append(i)
         else:
@@ -1286,6 +1310,7 @@ def _run_fedbuff(sim: Simulation, policy, orch: OrchestratorConfig,
         for v in [v for v in version_params if v not in keep]:
             del version_params[v]
         n_agg += 1
+        sim.round_idx = n_agg
         if tel.enabled:
             tel.instant("server", "BUFFER_MERGE", now, version=version,
                         n_updates=len(buffer))
@@ -1358,12 +1383,16 @@ def run_orchestrated(run_cfg: FLRunConfig,
                      fleet_cfg: Optional[FleetConfig] = None,
                      orch: Optional[OrchestratorConfig] = None,
                      verbose: bool = False,
-                     telemetry=None) -> History:
+                     telemetry=None,
+                     jax_profile: Optional[str] = None) -> History:
     """Run federated training under an arrival/aggregation policy.
 
     ``telemetry`` is an optional :class:`repro.telemetry.Telemetry`
     session; when absent (or NULL) the run is bitwise-identical to the
     uninstrumented runner and allocates nothing on the event path.
+    ``jax_profile`` is a directory: the round loop runs under
+    ``jax.profiler`` and its trace (with the ``fl.*`` host spans) is
+    written there.
     """
     orch = orch or OrchestratorConfig()
     sim = Simulation(run_cfg, fleet_cfg, telemetry=telemetry)
@@ -1375,7 +1404,5 @@ def run_orchestrated(run_cfg: FLRunConfig,
             "(sync/semisync): fedbuff's cross-version stream has no "
             "per-cell round barrier to ship partials at")
     runner = _run_round_based if policy.round_based else _run_fedbuff
-    if sim.tel.enabled and sim.tel.jax_profile and sim.tel.out_dir:
-        with profile_trace(sim.tel.out_dir):
-            return runner(sim, policy, orch, verbose)
-    return runner(sim, policy, orch, verbose)
+    with profiler.profile_trace(jax_profile):
+        return runner(sim, policy, orch, verbose)

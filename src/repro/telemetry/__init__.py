@@ -8,8 +8,9 @@ dimensions, also the backing store of every ``RoundLog`` — and (2) a
 spans and instants exportable as Perfetto/Chrome-trace JSON and JSONL.
 :mod:`~repro.telemetry.manifest` stamps artifacts with full provenance
 (config, seeds, versions, git sha, trace-signature hash);
-:mod:`~repro.telemetry.profiler` optionally wraps a run in
-``jax.profiler`` for kernel-level host timing.
+:mod:`~repro.telemetry.profiler` owns the wall clock: the round path's
+``fl.*`` host spans and transfer counts, recorded on ``jax.profiler``'s
+clock whenever a profiler trace runs (``--jax-profile DIR``).
 
 PR 8 adds the learning-dynamics layer on top: :mod:`~repro.telemetry.
 learning` (streaming update-norm / compression-error / contribution

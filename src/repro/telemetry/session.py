@@ -34,12 +34,10 @@ class Telemetry:
 
     enabled = True
 
-    def __init__(self, out_dir: Optional[str] = None, *,
-                 jax_profile: bool = False, rollup=None,
+    def __init__(self, out_dir: Optional[str] = None, *, rollup=None,
                  trace_sample: Optional[float] = None,
                  trace_seed: int = 0):
         self.out_dir = out_dir
-        self.jax_profile = jax_profile
         # fleet-scale bounds (both off by default — exact telemetry):
         # `rollup` is a RollupPolicy folding device-labeled metrics into
         # per-cell sketches once set_fleet_size crosses its threshold;
@@ -115,7 +113,6 @@ class _NullTelemetry:
 
     enabled = False
     out_dir = None
-    jax_profile = False
     registry = None
     sink = None
     health = None

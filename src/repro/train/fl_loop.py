@@ -28,6 +28,7 @@ import numpy as np
 from repro.core.anycost import (AnycostClient, AnycostServer,  # noqa: F401
                                 DEFAULT_ALPHA_BUCKETS)
 from repro.sysmodel.population import FleetConfig
+from repro.telemetry import profiler
 
 PyTree = Any
 
@@ -296,7 +297,7 @@ def _device_batches(rng, x, y, idx, batch_size: int, tau: float):
     order = np.concatenate([rng.permutation(n)
                             for _ in range(math.ceil(steps * bs / n) + 1)])
     sel = idx[order[:steps * bs]].reshape(steps, bs)
-    return {"images": jnp.asarray(x[sel]), "labels": jnp.asarray(y[sel])}
+    return {"images": profiler.put(x[sel]), "labels": profiler.put(y[sel])}
 
 
 def run_fl(run_cfg: FLRunConfig, fleet_cfg: Optional[FleetConfig] = None,

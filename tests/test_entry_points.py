@@ -56,3 +56,21 @@ def test_compile_cache_placement(tmp_path, from_env):
     nonce = zlib.crc32(str(tmp_path).encode())
     assert _probe(want if from_env else None, nonce) == [want, want]
     assert set(os.listdir(want)) - before
+
+
+def test_jax_profile_writes_round_spans_without_telemetry(tmp_path):
+    """--jax-profile DIR needs no --telemetry-dir: the round loop runs
+    under the profiler and its trace, holding the fl.round spans, lands
+    in DIR."""
+    from bench import span_reduce, trace_reduce
+    prof = tmp_path / "prof"
+    args = build_parser().parse_args(
+        ["--mode", "fl", "--method", "fedavg", "--devices", "3",
+         "--rounds", "1", "--n-train", "96", "--n-test", "32",
+         "--eval-every", "1", "--lr", "0.05", "--jax-profile", str(prof)])
+    assert args.telemetry_dir is None
+    run_fl(args)
+    _, spans = span_reduce.read(trace_reduce.find_xplane(str(prof)))
+    names = [s.name for s in spans]
+    assert names.count("fl.round") == 1
+    assert "fl.eval" in names

@@ -342,8 +342,8 @@ def test_session_flush_without_dir_raises():
 
 def test_profile_trace_fails_when_profiler_cannot_start(tmp_path,
                                                         monkeypatch):
-    """--jax-profile on a jaxlib whose profiler cannot start fails the
-    run instead of running on without a profile."""
+    """--jax-profile DIR on a jaxlib whose profiler cannot start fails
+    the run instead of running on without a profile."""
     import jax
 
     from repro.telemetry import profile_trace
@@ -353,5 +353,15 @@ def test_profile_trace_fails_when_profiler_cannot_start(tmp_path,
 
     monkeypatch.setattr(jax.profiler, "start_trace", refuse)
     with pytest.raises(RuntimeError, match="profiler unavailable"):
-        with profile_trace(str(tmp_path)):
+        with profile_trace(str(tmp_path / "prof")):
             pass
+    # no directory, no profiler: the body runs as it is
+    with profile_trace(None) as log_dir:
+        assert log_dir is None
+
+
+def test_telemetry_session_holds_no_profiler_switch():
+    """The profiler is the run's (--jax-profile DIR), not the telemetry
+    session's: a profiled run is the plain program plus its spans."""
+    assert not hasattr(Telemetry(), "jax_profile")
+    assert not hasattr(NULL_TELEMETRY, "jax_profile")
