@@ -12,18 +12,16 @@ client/server code drives the pod-scale integration through
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Any, Callable, Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import aggregation, compression, shrinking
 from repro.core.schedule import Strategy
 from repro.models.registry import Model, build_model, loss_fn
 from repro.telemetry import profiler
-from repro.utils.pytree import tree_sub
+from repro.utils.pytree import tree_size, tree_sub
 
 PyTree = Any
 
@@ -66,6 +64,7 @@ class AnycostClient:
         self._step_cache: dict = {}
         self._fast_step_cache: dict = {}
         self._finish_cache: dict = {}
+        self._shrink_cache: dict = {}
 
     def _local_steps(self, alpha: float, n_steps: int):
         key = (alpha, n_steps)
@@ -124,37 +123,48 @@ class AnycostClient:
                     w_per_sample: float = 0.0) -> ClientUpdate:
         """One full device round: shrink -> train -> compress -> (upload)."""
         alpha = bucket_alpha(strategy.alpha, self.alpha_buckets)
-        sub = shrinking.shrink(sorted_global, alpha, self.spec)
+        sub = self.shrink(sorted_global, alpha)
         n_steps = jax.tree_util.tree_leaves(batches)[0].shape[0]
         trained = self._local_steps(alpha, n_steps)(sub, batches)
         return self.finish_round(sorted_global, alpha, trained, strategy,
                                  n_steps, key, planner=planner,
                                  w_per_sample=w_per_sample, sub=sub)
 
-    def _finish_core_raw(self, alpha: float):
-        spec = self.spec
+    def shrink(self, sorted_global: PyTree, alpha: float) -> PyTree:
+        """jit'd EMS slice of the sorted global model (one compile per
+        width bucket)."""
+        if alpha not in self._shrink_cache:
+            spec = self.spec
 
-        def core(sub, trained, rho, n_levels, key):
-            update_sub = tree_sub(sub, trained)
-            full_update, width_mask = shrinking.expand_update(
-                update_sub, None, alpha, spec)
-            comp = compression.compress_update(full_update, 0.0, key,
-                                               rho=rho, n_levels=n_levels)
-            mask = jax.tree.map(lambda a, b: a * b, width_mask, comp.mask)
-            values = jax.tree.map(lambda v, m: v * m, comp.values, mask)
-            return values, mask, comp.bits
+            @jax.jit
+            def shrink(params):
+                return shrinking.shrink(params, alpha, spec)
 
-        return core
+            self._shrink_cache[alpha] = shrink
+        return self._shrink_cache[alpha](sorted_global)
 
     def _finish_core(self, alpha: float):
         """jit'd shrink-residual -> expand -> compress pipeline for one
         width bucket. One compile per alpha; (rho, n_levels, key) are
         traced, so per-round targets never retrace."""
-        if alpha not in self._finish_cache:
-            self._finish_cache[alpha] = jax.jit(
-                self._finish_core_raw(alpha))
-        return self._finish_cache[alpha]
+        if alpha in self._finish_cache:
+            return self._finish_cache[alpha]
+        spec = self.spec
 
+        @jax.jit
+        def core(sub, trained, rho, n_levels, key):
+            update_sub = tree_sub(sub, trained)       # u = w_before - w_after
+            full_update, width_mask = shrinking.expand_update(
+                update_sub, None, alpha, spec)
+            comp = compression.compress_update(full_update, 0.0, key,
+                                               rho=rho, n_levels=n_levels)
+            # the transmitted mask = width mask AND sparsity mask
+            mask = jax.tree.map(lambda a, b: a * b, width_mask, comp.mask)
+            values = jax.tree.map(lambda v, m: v * m, comp.values, mask)
+            return values, mask, comp.bits
+
+        self._finish_cache[alpha] = core
+        return core
 
     def finish_plan(self, beta: float,
                     planner: Optional[compression.BetaPlanner] = None
@@ -172,7 +182,6 @@ class AnycostClient:
                           ) -> ClientUpdate:
         """Assemble a ClientUpdate from an already-decoded (values, mask,
         bits) triple (the jit'd / vmapped finish cores)."""
-        from repro.utils.pytree import tree_size
         n = tree_size(values)          # full-coordinate size
         n_samples = n_steps * self.batch_size
         bits = float(profiler.read(bits))
@@ -188,10 +197,9 @@ class AnycostClient:
                           sub: PyTree,
                           planner: Optional[compression.BetaPlanner] = None,
                           w_per_sample: float = 0.0) -> ClientUpdate:
-        """Jit'd variant of :meth:`finish_round` for the orchestrator's hot
-        path (hundreds of completions per simulated run). Numerically
-        equivalent up to jit fusion — not bitwise identical to the eager
-        path, which the synchronous loop keeps for reproducibility."""
+        """Decode a trained sub-model, given the sub-model it started from,
+        into the uploaded update: one call of the width bucket's compiled
+        finish program."""
         rho, n_levels = self.finish_plan(float(strategy.beta), planner)
         values, mask, bits = self._finish_core(alpha)(sub, trained, rho,
                                                       n_levels, key)
@@ -209,33 +217,15 @@ class AnycostClient:
 
         Split out of :meth:`local_round` so the orchestrator's client pool
         can train many clients in one vmapped call and decode each result
-        here. ``alpha`` must be the bucketed width actually trained.
+        here. ``alpha`` must be the bucketed width actually trained; the
+        sub-model it started from is sliced from ``sorted_global`` unless
+        given as ``sub``.
         """
         if sub is None:
-            sub = shrinking.shrink(sorted_global, alpha, self.spec)
-        update_sub = tree_sub(sub, trained)          # u = w_before - w_after
-        full_update, width_mask = shrinking.expand_update(
-            update_sub, sorted_global, alpha, self.spec)
-        beta = float(strategy.beta)
-        if planner is not None:
-            rho, levels = planner.plan(beta)
-            comp = compression.compress_update(full_update, beta, key,
-                                               rho=jnp.float32(rho),
-                                               n_levels=jnp.float32(levels))
-        else:
-            comp = compression.compress_update(full_update, beta, key)
-        # the transmitted mask = width mask AND sparsity mask
-        mask = jax.tree.map(lambda a, b: a * b, width_mask, comp.mask)
-        values = jax.tree.map(lambda v, m: v * m, comp.values, mask)
-        from repro.utils.pytree import tree_size
-        n = tree_size(full_update)
-        n_samples = n_steps * self.batch_size
-        bits = float(profiler.read(comp.bits))
-        return ClientUpdate(
-            values=values, mask=mask, alpha=alpha, beta_target=beta,
-            beta_realized=bits / (32.0 * n),
-            bits=bits, n_samples=n_samples,
-            flops=alpha * w_per_sample * n_samples)
+            sub = self.shrink(sorted_global, alpha)
+        return self.finish_round_fast(alpha, trained, strategy, n_steps, key,
+                                      sub=sub, planner=planner,
+                                      w_per_sample=w_per_sample)
 
 
 class AnycostServer:

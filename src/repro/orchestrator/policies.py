@@ -172,7 +172,7 @@ class SyncPolicy:
 
     name = "sync"
     round_based = True
-    pool_default = False      # guarantees bitwise identity with the old loop
+    pool_default = False      # one client at a time, as the old loop
 
     def __init__(self, cfg: OrchestratorConfig):
         self.cfg = cfg

@@ -3,9 +3,9 @@
 ``run_orchestrated(run_cfg, fleet_cfg, orch_cfg)`` executes any method
 (anycostfl / baselines) under any arrival policy (sync / semisync /
 fedbuff).  ``train/fl_loop.run_fl`` delegates here with the sync policy,
-which reproduces the pre-orchestrator loop bit-for-bit: the per-device
-sequence of numpy-RNG draws, JAX key splits, and cost-accumulation float
-ops is kept identical (see ``Simulation.prepare`` / ``materialize``).
+which keeps the pre-orchestrator loop's order: the per-device sequence of
+numpy-RNG draws, JAX key splits, and cost-accumulation float ops is kept
+identical (see ``Simulation.prepare`` / ``materialize``).
 
 Timeline semantics:
 
@@ -188,7 +188,6 @@ class Simulation:
         self.key = jax.random.PRNGKey(run_cfg.seed + 1)
         self.pool = ClientPool(self.client)
         self._agg_fast = None
-        self._shrink_cache: dict = {}
         # the round (fedbuff: the merge) in progress, for the fl.* spans
         self.round_idx = 0
 
@@ -377,30 +376,22 @@ class Simulation:
         return self.client._local_steps(p.alpha, p.n_steps)(sub, p.batches)
 
     def materialize(self, p: PendingUpdate, trained: PyTree,
-                    sorted_params: PyTree, *, fast: bool = False,
+                    sorted_params: PyTree, *,
                     sub: Optional[PyTree] = None) -> PendingUpdate:
         """Decode the trained sub-model into a ClientUpdate + realized costs
-        (Eq. 6-9). The default path keeps float-op order identical to the
-        old loop; ``fast=True`` routes through the jit'd finish pipeline
-        (equivalent up to fusion) for high-event-rate policies."""
+        (Eq. 6-9). AnycostFL's finish is the width bucket's compiled
+        program (``AnycostClient.finish_round``); ``sub``, the sub-model
+        the client started from, is sliced from ``sorted_params`` if not
+        given."""
         with profiler.span("fl.finish", round=self.round_idx,
                            client=p.client_id):
             rc = self.run_cfg
             env, strat = p.env, p.strat
             if rc.method == "anycostfl":
-                if fast:
-                    if sub is None:
-                        sub = shrinking.shrink(sorted_params, p.alpha,
-                                               self.spec)
-                    upd = self.client.finish_round_fast(
-                        p.alpha, trained, strat, p.n_steps, p.key, sub=sub,
-                        planner=self.planner if rc.use_fgc else None,
-                        w_per_sample=self.W)
-                else:
-                    upd = self.client.finish_round(
-                        sorted_params, p.alpha, trained, strat, p.n_steps,
-                        p.key, planner=self.planner if rc.use_fgc else None,
-                        w_per_sample=self.W, sub=sub)
+                upd = self.client.finish_round(
+                    sorted_params, p.alpha, trained, strat, p.n_steps,
+                    p.key, planner=self.planner if rc.use_fgc else None,
+                    w_per_sample=self.W, sub=sub)
                 if not rc.use_fgc:
                     # transmit the raw (width-masked) update
                     upd = dataclasses.replace(
@@ -437,12 +428,9 @@ class Simulation:
             return p
 
     def shrink_fast(self, sorted_params: PyTree, alpha: float) -> PyTree:
-        """jit'd EMS slice (one compile per width bucket) for hot paths."""
-        if alpha not in self._shrink_cache:
-            spec = self.spec
-            self._shrink_cache[alpha] = jax.jit(
-                lambda p: shrinking.shrink(p, alpha, spec))
-        return self._shrink_cache[alpha](sorted_params)
+        """jit'd EMS slice (one compile per width bucket) for hot paths:
+        the client's own, which its finish also uses."""
+        return self.client.shrink(sorted_params, alpha)
 
     def aggregate(self, sorted_params: PyTree, accepted: list[PendingUpdate],
                   weights: jax.Array, *, fast: bool = False) -> PyTree:
@@ -823,8 +811,7 @@ def _run_round_based(sim: Simulation, policy, orch: OrchestratorConfig,
             en, fl, cb = 0.0, 0.0, 0.0
             en_cmp = en_com = 0.0
             for p, tr in zip(live, trained):
-                sim.materialize(p, tr, sorted_params, fast=use_pool,
-                                sub=subs.get(p.alpha))
+                sim.materialize(p, tr, sorted_params, sub=subs.get(p.alpha))
                 p.dispatched_at = t_wall
                 p.completes_at = t_wall + p.duration
                 # dispatch->arrival flight time goes to the always-live
@@ -1249,7 +1236,7 @@ def _run_fedbuff(sim: Simulation, policy, orch: OrchestratorConfig,
         gamma = orch.staleness_exponent
         for b, j, tr in zip(buffer, jobs, trained):
             sim.materialize(b, tr, version_params[b.version],
-                            fast=use_pool, sub=j.sub_params)
+                            sub=j.sub_params)
             en += b.energy
             en_cmp += b.e_cmp
             en_com += b.e_com

@@ -149,8 +149,10 @@ def test_pool_matches_sequential_clients():
 
 
 def test_sync_matches_pre_refactor_golden():
-    """The orchestrator's sync policy is bit-equivalent to the loop it
-    replaced (golden captured from the pre-orchestrator fl_loop)."""
+    """Pins the sync policy's trajectory on its default (unpooled) route,
+    whose client finish is the width bucket's compiled program: any change
+    to the round's numerics shows here (regenerate with
+    ``scripts/regen_golden.py`` only when it is meant)."""
     path = os.path.join(os.path.dirname(__file__), "goldens",
                         "fl_sync_golden.json")
     g = json.load(open(path))

@@ -36,10 +36,6 @@ def _spans(log_dir):
     return spans
 
 
-def _n_params(hist) -> int:
-    return sum(x.size for x in jax.tree.leaves(hist.params))
-
-
 def _batch_bytes(hist) -> int:
     """Minibatch bytes of every client trained: (steps, B) float32 images
     of 28x28x1 and int32 labels."""
@@ -68,8 +64,8 @@ def test_every_span_appears_once_per_round_or_client(unpooled):
     assert counts["fl.schedule"] == CFG.rounds * N_DEVICES
     for name in ("fl.batches", "fl.local_train", "fl.finish"):
         assert counts[name] == trained, name
-    # minibatch images and labels, and the FGC's two segment-id puts
-    assert counts["fl.h2d"] == 4 * trained
+    # minibatch images and labels
+    assert counts["fl.h2d"] == 2 * trained
     # each client's bit count, the eval's accuracy and loss
     assert counts["fl.sync"] == trained + 2 * CFG.rounds
     rounds = sorted(s.stats["round"] for s in spans if s.name == "fl.round")
@@ -104,25 +100,25 @@ def test_round_self_time_and_children_add_up(unpooled):
         assert totals["fl.round"].self_s > 0
 
 
-def test_h2d_bytes_match_shape_arithmetic_on_the_eager_route(unpooled):
-    hist, spans = unpooled
-    totals = span_reduce.reduce(spans, span_reduce.round_window(spans))
-    trained = sum(r.n_clients for r in hist.rounds)
-    want = _batch_bytes(hist) + trained * 2 * 4 * _n_params(hist)
-    assert totals["fl.h2d"].stats["bytes"] == want
-    got = span_reduce.per_round(totals, CFG.rounds)
-    assert got["h2d.bytes"] == want / CFG.rounds
-    assert set(got) == set(span_reduce.METRICS)
-
-
-def test_jitted_finish_puts_no_segment_ids(tmp_path):
-    """The pooled route's finish is traced for jit: its segment ids are
-    constants of the program, not transfers."""
-    hist = _run(str(tmp_path), use_pool=True, rounds=1)
-    spans = _spans(tmp_path)
-    got = sum(s.stats["bytes"] for s in spans if s.name == "fl.h2d")
-    assert got == _batch_bytes(hist)
+@pytest.mark.parametrize("use_pool", [False, True])
+def test_h2d_bytes_are_the_minibatches(use_pool, request, tmp_path):
+    """On either route the finish is one compiled program per width: its
+    segment ids are constants of the program, not transfers, so a round
+    puts only the minibatches."""
+    if use_pool:
+        rounds = 1
+        hist = _run(str(tmp_path), use_pool=True, rounds=rounds)
+        spans = _spans(tmp_path)
+    else:
+        rounds = CFG.rounds
+        hist, spans = request.getfixturevalue("unpooled")
     assert any(s.name == "fl.local_train" for s in spans)
+    totals = span_reduce.reduce(spans, span_reduce.round_window(spans))
+    want = _batch_bytes(hist)
+    assert totals["fl.h2d"].stats["bytes"] == want
+    got = span_reduce.per_round(totals, rounds)
+    assert got["h2d.bytes"] == want / rounds
+    assert set(got) == set(span_reduce.METRICS)
 
 
 def test_profiler_leaves_the_round_bitwise_unchanged(unpooled):
