@@ -69,10 +69,8 @@ def readings(workload: dict, config: dict, seed: int) -> dict:
         out[name]["worst_leaf_gap"] = compare.worst_leaf_gap(
             side.deltas[0], ref.deltas[0])
         out[name]["losses"] = side.losses
-        out[name]["round0"] = {f"{a}.{b}": v
-                               for (a, b), v in side.deltas[0].items()}
-    out["reference"] = {"losses": ref.losses, "round0": {
-        f"{a}.{b}": v for (a, b), v in ref.deltas[0].items()}}
+        out[name]["round0"] = side.deltas[0]
+    out["reference"] = {"losses": ref.losses, "round0": ref.deltas[0]}
     return out
 
 

@@ -7,8 +7,9 @@ runner's round loop (``_run_round_based``, one round per call, the global
 model carried from call to call).  The program has no per-round hook and
 no wall-clock stop, so the cell reaches into it here:
 
-* ``runner.make_image_task`` is swapped for the benchmark's generator while
-  the Simulation is built, and ``Simulation.params`` is set to the
+* the program's task function that the model's task file names
+  (``PROGRAM_TASK``) is swapped for the task file's generator while the
+  Simulation is built, and ``Simulation.params`` is set to the
   benchmark's weights;
 * the Simulation's ``prepare``, ``sort_params``, ``materialize``,
   ``aggregate``, ``evaluate``, ``pool.train_shared`` and
@@ -33,8 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import data, reference
-from bench.paths import ROOT
+from bench import reference
+from bench.paths import BENCH, ROOT
 
 sys.path.insert(0, str(ROOT / "src"))
 
@@ -71,11 +72,11 @@ class Round(NamedTuple):
 class Checked:
     """One checked round: its inputs and what the program made of them."""
     clients: list                   # reference.Client, in dispatch order
-    delta_norms: dict               # (layer, leaf) -> |new - sorted|
+    delta_norms: dict               # leaf path -> |new - sorted|
     test_loss: float
     change_sq: float = 0.0          # sum of |trained - start|^2 of clients
     updates: Optional[list] = None  # start - trained of each client, flat
-    change: Optional[dict] = None   # (layer, leaf) -> new - sorted
+    change: Optional[dict] = None   # leaf path -> new - sorted
 
 
 class CompileCounter:
@@ -107,7 +108,8 @@ class CompileCounter:
 
 class Cell:
     def __init__(self, workload: dict, config: dict, seed: int,
-                 compiles: CompileCounter, *, trace: bool = False):
+                 compiles: CompileCounter, *, trace: bool = False,
+                 bench_dir=BENCH):
         from repro.orchestrator import runner
         from repro.orchestrator.policies import (OrchestratorConfig,
                                                  make_policy)
@@ -115,15 +117,16 @@ class Cell:
         from repro.train.fl_loop import FLRunConfig
 
         self.workload, self.config, self.seed = workload, config, seed
-        self.model = reference.load_model(config["arch"])
+        self.model = reference.load_model(config["arch"], bench_dir)
+        self.task = reference.load_task(self.model.TASK, bench_dir)
         self.compiles = compiles
         self.trace = trace
         self._runner = runner
         key = reference.seed_key(seed)
         k_data, k_init = jax.random.split(key)
-        self.train, self.test = data.make_task(
-            k_data, config["n_train"], config["n_test"],
-            config["image_shape"], config["n_classes"])
+        made = self.task.make(k_data, config)
+        # the test split as the reference reads it
+        self.test = self.task.rows(made[1])
 
         rc = FLRunConfig(
             arch=config["arch"], method="anycostfl", rounds=1,
@@ -140,17 +143,16 @@ class Cell:
         self.use_pool = bool(workload["use_pool"])
         self.orch = OrchestratorConfig(policy=POLICY, agg_route=ROUTE,
                                        use_pool=self.use_pool)
-        made = (self.train, self.test)
-        saved = runner.make_image_task
-        runner.make_image_task = lambda *_a, **_k: made
+        name = self.task.PROGRAM_TASK
+        saved = getattr(runner, name)
+        setattr(runner, name, lambda *_a, **_k: made)
         try:
             sim = runner.Simulation(rc, fleet)
         finally:
-            runner.make_image_task = saved
+            setattr(runner, name, saved)
         sim.agg_route = sim.resolve_agg_route(self.orch.agg_route)
         self.policy = make_policy(self.orch, fleet_T_max=fleet.T_max)
-        sim.params = jax.jit(reference.init_params, static_argnums=0)(
-            self.model, k_init)
+        sim.params = jax.jit(self.model.init)(k_init)
         self.sim = sim
         self.warm_alphas, self.warm_cohorts = self._warm_plan()
         self._jobs: list = []
@@ -214,8 +216,8 @@ class Cell:
                     sub = sim.shrink_fast(sorted_params, p.alpha)
                 self._capture.clients.append(reference.Client(
                     alpha=float(p.alpha), beta=float(p.strat.beta),
-                    key=p.key, images=np.asarray(p.batches["images"]),
-                    labels=np.asarray(p.batches["labels"])))
+                    key=p.key, batches=jax.tree.map(np.asarray,
+                                                    p.batches)))
                 u = _flat_update(sub, trained)
                 self._capture.change_sq += float(np.sum(np.square(
                     u.astype(np.float64))))
@@ -224,9 +226,9 @@ class Cell:
 
         def capture_delta(out, sorted_params, *_a, **_kw):
             if self._capture is not None:
-                norms = _delta_norms(out, sorted_params)
                 self._capture.delta_norms = {
-                    (a, b): float(v) for (a, b), v in norms.items()}
+                    k: float(v) for k, v in
+                    _delta_norms(out, sorted_params).items()}
                 if self._capture.updates is not None:
                     self._capture.change = _change(out, sorted_params)
 
@@ -288,9 +290,8 @@ class Cell:
     def _batches(self, lanes: int):
         from repro.orchestrator import client_pool
         steps, bs, _ = self._shapes()
-        one = {"images": jnp.asarray(np.zeros(
-            (steps, bs) + tuple(self.config["image_shape"]), np.float32)),
-            "labels": jnp.asarray(np.zeros((steps, bs), np.int32))}
+        one = jax.tree.map(jnp.asarray,
+                           self.task.zero_batch(self.config, steps, bs))
         return one if lanes == 1 else client_pool._tree_stack([one] * lanes)
 
     def compile_all(self) -> None:
@@ -401,15 +402,12 @@ def _flat_update(start, trained) -> np.ndarray:
 
 
 def _change(new_params, old_params) -> dict:
-    return jax.device_get({(layer, leaf): x - old_params[layer][leaf]
-                           for layer, leaves in new_params.items()
-                           for leaf, x in leaves.items()})
+    old = reference.flat(old_params)
+    return jax.device_get({k: x - old[k] for k, x in
+                           reference.flat(new_params).items()})
 
 
 def _delta_norms(new_params, old_params) -> dict:
-    out = {}
-    for layer, leaves in new_params.items():
-        for leaf, x in leaves.items():
-            out[(layer, leaf)] = jnp.linalg.norm(
-                (x - old_params[layer][leaf]).reshape(-1))
-    return jax.device_get(out)
+    old = reference.flat(old_params)
+    return jax.device_get({k: jnp.linalg.norm((x - old[k]).reshape(-1))
+                           for k, x in reference.flat(new_params).items()})

@@ -33,7 +33,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     sys.path.insert(0, str(ROOT / "src"))
     import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -55,12 +54,11 @@ def main(argv=None) -> int:
                            batch_size=cfg["batch_size"])
     pool = ClientPool(client)
     ref_model = reference.load_model(cfg["arch"])
-    full = jax.eval_shape(lambda k: reference.init_params(ref_model, k),
-                          jax.random.PRNGKey(0))
+    task = reference.load_task(ref_model.TASK)
+    full = jax.eval_shape(ref_model.init, jax.random.PRNGKey(0))
     n = cfg["n_train"] // cfg["n_devices"]
     bs = min(cfg["batch_size"], n)
     steps = max(int(round(cfg["tau"] * n / bs)), 1)
-    shape = tuple(cfg["image_shape"])
     for alpha in args.alphas or cfg["alpha_buckets"]:
         sub = jax.eval_shape(lambda p: shrinking.shrink(p, alpha, spec),
                              full)
@@ -68,11 +66,9 @@ def main(argv=None) -> int:
             s.shape, s.dtype, sharding=chip), sub)
         for k in args.pads:
             lead = () if k == 1 else (k,)
-            batches = {
-                "images": jax.ShapeDtypeStruct(lead + (steps, bs) + shape,
-                                               jnp.float32, sharding=chip),
-                "labels": jax.ShapeDtypeStruct(lead + (steps, bs),
-                                               jnp.int32, sharding=chip)}
+            batches = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                lead + x.shape, x.dtype, sharding=chip),
+                task.zero_batch(cfg, steps, bs))
             fn = client._local_steps_fast(alpha, steps) if k == 1 \
                 else pool._vmapped(alpha, steps, k, True)
             t0 = time.perf_counter()

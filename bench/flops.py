@@ -1,15 +1,9 @@
-"""Model FLOPs per sample from the EMS sub-model's parameter shapes.
+"""Model FLOPs per sample of the EMS sub-model at width ``alpha``.
 
-A convolution of kernel ``k`` from ``c_in`` to ``c_out`` channels over an
-``s x s`` output map costs ``2 k^2 c_in c_out s^2`` multiply-adds counted
-as two FLOPs each; a dense layer ``2 d_in d_out``.  At width ``alpha``
-every EMS group keeps ``ceil(size * sqrt(alpha))`` channels, so a layer
-between two groups costs about ``alpha`` of its full work and the first and
-last layers about ``sqrt(alpha)``.  Training is the forward pass plus the
-backward pass, which computes a gradient for the weights (one forward's
-worth) and one for the layer's input (another), except at the first layer,
-whose input is the data.  Bias adds, activations, pooling and the loss are
-not counted.
+Every width group keeps ``n`` of its ``size`` channels
+(``reference.widths``), and each leaf it cuts keeps ``outer * n * block``
+along the cut axis.  The model file counts the FLOPs of a model with those
+leaf shapes (``layer_flops``, ``train_flops``).
 """
 from __future__ import annotations
 
@@ -17,28 +11,19 @@ from bench import reference
 
 
 def sub_shapes(model, alpha: float) -> dict:
-    """``{layer: weight shape}`` of the width-``alpha`` sub-model."""
-    w = reference.widths(model, alpha)
-    out_w = {g[2]: w[g[0]] for g in model.GROUPS}
-    in_w = {g[3]: w[g[0]] * g[4] for g in model.GROUPS}
-    shapes = {}
-    for name, kind, k, c_in, c_out, _, _ in model.LAYERS:
-        c_in, c_out = in_w.get(name, c_in), out_w.get(name, c_out)
-        shapes[name] = (k, k, c_in, c_out) if kind == "conv" \
-            else (c_in, c_out)
-    return shapes
+    """``{leaf path: shape}`` of the width-``alpha`` sub-model."""
+    shapes = {k: list(s) for k, s in
+              reference.flat(model.param_shapes()).items()}
+    n = reference.widths(model, alpha)
+    for g in model.width_groups():
+        for e in g.entries:
+            shapes[e.path][e.axis] = e.outer * n[g.name] * e.block
+    return {k: tuple(s) for k, s in shapes.items()}
 
 
-def layer_forward(model, alpha: float) -> dict:
+def layer_forward(model, alpha: float = 1.0) -> dict:
     """Forward FLOPs per sample of each layer."""
-    shapes = sub_shapes(model, alpha)
-    out = {}
-    for name, kind, _, _, _, side, _ in model.LAYERS:
-        n = 1
-        for d in shapes[name]:
-            n *= d
-        out[name] = 2 * n * (side * side if kind == "conv" else 1)
-    return out
+    return model.layer_flops(sub_shapes(model, alpha))
 
 
 def forward(model, alpha: float = 1.0) -> int:
@@ -47,7 +32,4 @@ def forward(model, alpha: float = 1.0) -> int:
 
 def train(model, alpha: float = 1.0) -> int:
     """Forward plus backward FLOPs per sample."""
-    per = layer_forward(model, alpha)
-    first = model.LAYERS[0][0]
-    return sum(3 * f if name != first else 2 * f
-               for name, f in per.items())
+    return model.train_flops(sub_shapes(model, alpha))

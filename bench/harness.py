@@ -2,9 +2,10 @@
 
 Everything that belongs to a cell, a configuration or a per-layer metric
 is a file found by name: ``bench/workloads/<cell>.json``,
-``bench/configs/<config>.json``, ``bench/models/<arch>.py`` and
-``bench/metrics/<metric>.py``.  ``BENCHMARK.json`` says which metrics a
-cell reports.
+``bench/configs/<config>.json``, the reference model
+``bench/models/<arch>.py`` and its data ``bench/tasks/<task>.py`` (the
+interface is in ``bench/reference.py``), and ``bench/metrics/<metric>.py``.
+``BENCHMARK.json`` says which metrics a cell reports.
 """
 from __future__ import annotations
 
@@ -147,7 +148,8 @@ def run_cell(workload: dict, config: dict, seed: int, seconds: float,
 
     compiles = cell_mod.CompileCounter()
     phases = {"start": time.perf_counter() - t_start}
-    cell = cell_mod.Cell(workload, config, seed, compiles, trace=trace)
+    cell = cell_mod.Cell(workload, config, seed, compiles, trace=trace,
+                         bench_dir=bench_dir)
     if break_program is not None:
         break_program(cell)
     phases["build"] = time.perf_counter() - t_start

@@ -30,16 +30,10 @@ from bench import harness  # noqa: E402
 
 def h2d_by_shapes(cell, rounds) -> float:
     """Bytes a window's rounds put on the device: each trained client's
-    minibatches (float32 images, int32 labels) and, on the unpooled
-    route, the eager FGC's int32 segment ids, put twice (the pooled
-    route's jitted finish holds them as constants)."""
-    import jax
-    import numpy as np
-    n_params = sum(int(np.prod(x.shape))
-                   for x in jax.tree.leaves(cell.sim.params))
-    per_sample = 4 * int(np.prod(cell.config["image_shape"])) + 4
-    seg_ids = 0 if cell.use_pool else 2 * 4 * n_params
-    return float(sum(samples * per_sample + seg_ids
+    minibatches, at the task file's bytes per sample (the compiled FGC
+    finish holds its segment ids as constants on both routes)."""
+    per_sample = cell.task.bytes_per_sample(cell.config)
+    return float(sum(samples * per_sample
                      for r in rounds for _, samples in r.jobs))
 
 

@@ -1,12 +1,14 @@
 """FedAvg CNN (McMahan et al. 2017), as AnycostFL section V-A trains it.
 
-Layer table read by ``bench/reference.py``: NHWC images, 'SAME' 5x5
-convolutions with bias and ReLU, each followed by a 2x2 max pool, then a
-ReLU dense layer and the 10-class output.  The flatten before ``dense1``
-is (H, W, C) with the channel fastest.
+Layer table read by ``bench/cnn.py``: 'SAME' 5x5 convolutions with bias
+and ReLU, each followed by a 2x2 max pool, then a ReLU dense layer and the
+10-class output.  Data from ``bench/tasks/images.py``.
 """
+import functools
 
-IMAGE = (28, 28, 1)
+from bench import cnn
+
+TASK = "images"
 
 # (name, kind, kernel, c_in, c_out, output map side before pooling, pool)
 LAYERS = (
@@ -24,3 +26,10 @@ GROUPS = (
     ("conv2", 64, "conv2", "dense1", 49),
     ("dense1", 512, "dense1", "dense2", 1),
 )
+
+param_shapes = functools.partial(cnn.param_shapes, LAYERS)
+init = functools.partial(cnn.init, LAYERS)
+loss = functools.partial(cnn.loss, LAYERS)
+layer_flops = functools.partial(cnn.layer_flops, LAYERS)
+train_flops = functools.partial(cnn.train_flops, LAYERS)
+width_groups = functools.partial(cnn.width_groups, LAYERS, GROUPS)

@@ -1,13 +1,15 @@
 """VGG-9 for 32x32x3 images, as AnycostFL section V-A trains it.
 
-Layer table read by ``bench/reference.py``: six 'SAME' 3x3 convolutions
-with bias and ReLU (64, 64, 128, 128, 256, 256 channels), a 2x2 max pool
-after the 2nd, 4th and 6th, then dense 512, 512 with ReLU and the
-10-class output.  The flatten before ``dense1`` is (H, W, C) with the
-channel fastest.
+Layer table read by ``bench/cnn.py``: six 'SAME' 3x3 convolutions with
+bias and ReLU (64, 64, 128, 128, 256, 256 channels), a 2x2 max pool after
+the 2nd, 4th and 6th, then dense 512, 512 with ReLU and the 10-class
+output.  Data from ``bench/tasks/images.py``.
 """
+import functools
 
-IMAGE = (32, 32, 3)
+from bench import cnn
+
+TASK = "images"
 
 # (name, kind, kernel, c_in, c_out, output map side before pooling, pool)
 LAYERS = (
@@ -34,3 +36,10 @@ GROUPS = (
     ("dense1", 512, "dense1", "dense2", 1),
     ("dense2", 512, "dense2", "dense3", 1),
 )
+
+param_shapes = functools.partial(cnn.param_shapes, LAYERS)
+init = functools.partial(cnn.init, LAYERS)
+loss = functools.partial(cnn.loss, LAYERS)
+layer_flops = functools.partial(cnn.layer_flops, LAYERS)
+train_flops = functools.partial(cnn.train_flops, LAYERS)
+width_groups = functools.partial(cnn.width_groups, LAYERS, GROUPS)

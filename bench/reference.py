@@ -13,7 +13,34 @@ and the server subtracts the result.
 Matrix products run at ``Precision.HIGHEST``: on a TPU the default runs
 float32 products as one bfloat16 pass.  ``dtype=bfloat16`` turns the local
 training into the lower-precision control that the output check must
-reject.  The model comes from ``bench/models/<arch>.py``.
+reject.
+
+Everything that depends on the model family is in two files, found by
+name in the cell's directory (``bench/`` unless a test gives another) or
+else in ``bench/``.  A model file, ``models/<arch>.py``, provides
+
+* ``TASK``: the name of its task file;
+* ``param_shapes()``: ``{name: {... {leaf: shape}}}``, dicts to any depth;
+* ``init(key)``: float32 parameters of those shapes from the key;
+* ``loss(params, batch, precision=HIGHEST)``: the mean loss over the rows
+  of one minibatch dict, computed in the parameters' dtype;
+* ``layer_flops(shapes)`` and ``train_flops(shapes)``: forward FLOPs per
+  sample of each layer, and forward plus backward FLOPs per sample, of the
+  model whose leaves have the shapes ``{path: shape}``;
+* ``width_groups()``: its EMS width groups, a tuple of :class:`WidthGroup`.
+
+A task file, ``tasks/<TASK>.py``, provides
+
+* ``PROGRAM_TASK``: the program's task function in
+  ``repro.orchestrator.runner`` that the cell stands in for;
+* ``make(key, config)``: the seeded (train, test) splits, as that
+  function returns them;
+* ``rows(split)``: a split as one minibatch dict, rows first;
+* ``zero_batch(config, steps, batch)``: a minibatch dict of zeros for
+  ``steps`` steps of ``batch`` rows (warm-up and compile);
+* ``bytes_per_sample(config)``: the bytes one sample puts on the device.
+
+Leaves are addressed by their dotted path in the parameter dicts.
 """
 from __future__ import annotations
 
@@ -27,40 +54,81 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-MODELS = pathlib.Path(__file__).resolve().parent / "models"
+from bench.paths import BENCH
+
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def load_model(arch: str):
-    """The layer table of ``bench/models/<arch>.py``."""
-    path = MODELS / f"{arch}.py"
-    if not path.is_file():
-        raise FileNotFoundError(f"no reference model file {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_model_{arch}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+class Entry(NamedTuple):
+    """An axis of one leaf that a width group cuts, viewed as ``(outer,
+    size, block)``: flattened feature maps feeding a dense layer have
+    ``outer`` spatial positions, an attention projection's head has
+    ``block`` lanes."""
+    path: str
+    axis: int
+    outer: int = 1
+    block: int = 1
 
 
-def param_shapes(model) -> dict:
+class WidthGroup(NamedTuple):
+    """Channels that share one width: ranked by the norms of ``sort_by``'s
+    slices, cut alike in every entry."""
+    name: str
+    size: int
+    sort_by: Entry
+    entries: tuple        # Entry, ...
+
+
+def _load(kind: str, name: str, bench_dir):
+    for d in dict.fromkeys((pathlib.Path(bench_dir), BENCH)):
+        path = d / kind / f"{name}.py"
+        if path.is_file():
+            spec = importlib.util.spec_from_file_location(
+                f"bench_{kind}_{name}", path)
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            return mod
+    raise FileNotFoundError(f"no file {kind}/{name}.py in {bench_dir} "
+                            f"or {BENCH}")
+
+
+def load_model(arch: str, bench_dir=BENCH):
+    """The model file ``models/<arch>.py``."""
+    return _load("models", arch, bench_dir)
+
+
+def load_task(name: str, bench_dir=BENCH):
+    """The task file ``tasks/<name>.py``."""
+    return _load("tasks", name, bench_dir)
+
+
+# ------------------------------------------------------------------ leaves
+
+def flat(tree, prefix: str = "") -> dict:
+    """``{dotted path: leaf}`` of a tree of dicts, in the tree's order."""
     out = {}
-    for name, kind, k, c_in, c_out, _, _ in model.LAYERS:
-        w = (k, k, c_in, c_out) if kind == "conv" else (c_in, c_out)
-        out[name] = {"b": (c_out,), "w": w}
+    for k, v in tree.items():
+        if isinstance(v, dict):
+            out.update(flat(v, f"{prefix}{k}."))
+        else:
+            out[f"{prefix}{k}"] = v
     return out
 
 
-def leaf_names(model) -> list[tuple[str, str]]:
-    """Leaves in the order the update is flattened for FGC: layers by
-    name, then bias before weight."""
-    return [(layer, leaf) for layer in sorted(param_shapes(model))
-            for leaf in ("b", "w")]
+def nest(leaves: dict) -> dict:
+    """The tree of dicts whose :func:`flat` is ``leaves``."""
+    out: dict = {}
+    for path, v in leaves.items():
+        *parents, last = path.split(".")
+        node = out
+        for part in parents:
+            node = node.setdefault(part, {})
+        node[last] = v
+    return out
 
 
 def n_params(model) -> int:
-    return sum(int(np.prod(s)) for layer in param_shapes(model).values()
-               for s in layer.values())
+    return sum(int(np.prod(s)) for s in flat(model.param_shapes()).values())
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -70,143 +138,93 @@ def seed_key(seed: int) -> jax.Array:
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def init_params(model, key) -> dict:
-    """He-normal weights (std sqrt(2 / fan_in) for convs, sqrt(1 / fan_in)
-    for dense layers), zero biases, float32."""
-    shapes = param_shapes(model)
-    keys = jax.random.split(key, len(shapes))
-    out = {}
-    for k, (name, kind, *_rest) in zip(keys, model.LAYERS):
-        w = shapes[name]["w"]
-        fan_in = int(np.prod(w[:-1]))
-        scale = math.sqrt(2.0) if kind == "conv" else 1.0
-        out[name] = {"w": jax.random.normal(k, w, jnp.float32)
-                     * (scale / math.sqrt(fan_in)),
-                     "b": jnp.zeros(shapes[name]["b"], jnp.float32)}
-    return out
-
-
-# ------------------------------------------------------------------ forward
-
-def forward(model, params, images, precision=HIGHEST):
-    x = images.astype(params[model.LAYERS[0][0]]["w"].dtype)
-    last = model.LAYERS[-1][0]
-    for name, kind, _, _, _, _, pool in model.LAYERS:
-        p = params[name]
-        if kind == "conv":
-            x = jax.lax.conv_general_dilated(
-                x, p["w"], (1, 1), "SAME",
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                precision=precision) + p["b"]
-        else:
-            x = jnp.dot(x.reshape(x.shape[0], -1), p["w"],
-                        precision=precision) + p["b"]
-        if name != last:
-            x = jax.nn.relu(x)
-        if pool:
-            x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-    return x
-
-
-def cross_entropy(logits, labels):
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=-1))
-
-
 # ---------------------------------------------------------------------- EMS
 
 def widths(model, alpha: float) -> dict:
     m = math.sqrt(alpha)
-    return {g[0]: min(max(math.ceil(g[1] * m), 1), g[1])
-            for g in model.GROUPS}
+    return {g.name: min(max(math.ceil(g.size * m), 1), g.size)
+            for g in model.width_groups()}
 
 
-def _in_axis(params, layer):
-    return 2 if params[layer]["w"].ndim == 4 else 0
+def _view(x, e: Entry, size: int):
+    """``x`` with ``e``'s axis split into (outer, size, block)."""
+    s = x.shape
+    if s[e.axis] != e.outer * size * e.block:
+        raise ValueError(f"{e.path}: axis {e.axis} has {s[e.axis]} lanes, "
+                         f"not {e.outer} x {size} x {e.block}")
+    return x.reshape(s[:e.axis] + (e.outer, size, e.block) + s[e.axis + 1:])
 
 
-def _permute_in(w, axis, outer, size, idx):
-    """Take channels ``idx`` of an input axis laid out as (outer, size)."""
-    shape = w.shape
-    v = w.reshape(shape[:axis] + (outer, size) + shape[axis + 1:])
-    v = jnp.take(v, idx, axis=axis + 1)
-    return v.reshape(shape[:axis] + (outer * len(idx),)
-                     + shape[axis + 1:])
+def _unview(v, e: Entry):
+    s = v.shape
+    return v.reshape(s[:e.axis] + (-1,) + s[e.axis + 3:])
+
+
+def _importance(w, e: Entry, size: int):
+    """L2 norm of each of the ``size`` channels of ``w`` along ``e``."""
+    v = jnp.moveaxis(_view(w, e, size), e.axis + 1, -1).reshape(-1, size)
+    return jnp.sqrt(jnp.sum(jnp.square(v), axis=0))
 
 
 def sort_channels(model, params) -> dict:
-    """Rank each group's channels by the producer's output-slice norm,
-    descending, and permute producer and consumer alike."""
-    p = {k: dict(v) for k, v in params.items()}
-    for _, size, prod, cons, outer in model.GROUPS:
-        w = p[prod]["w"]
-        norms = jnp.sqrt(jnp.sum(jnp.square(w.reshape(-1, size)), axis=0))
-        idx = jnp.argsort(-norms)
-        p[prod] = {"w": jnp.take(w, idx, axis=-1),
-                   "b": jnp.take(p[prod]["b"], idx)}
-        p[cons] = dict(p[cons])
-        p[cons]["w"] = _permute_in(p[cons]["w"], _in_axis(p, cons), outer,
-                                   size, idx)
-    return p
+    """Rank each group's channels by the norms of its ``sort_by`` slices,
+    descending, and permute every entry of the group alike."""
+    p = flat(params)
+    for g in model.width_groups():
+        w = p[g.sort_by.path]
+        idx = jnp.argsort(-_importance(w, g.sort_by, g.size))
+        for e in g.entries:
+            p[e.path] = _unview(jnp.take(_view(p[e.path], e, g.size),
+                                         idx, axis=e.axis + 1), e)
+    return nest(p)
 
 
 def shrink(model, params, alpha: float) -> dict:
-    p = {k: dict(v) for k, v in params.items()}
-    for name, n in widths(model, alpha).items():
-        _, size, prod, cons, outer = next(g for g in model.GROUPS
-                                          if g[0] == name)
-        idx = jnp.arange(n)
-        p[prod] = {"w": p[prod]["w"][..., :n], "b": p[prod]["b"][:n]}
-        p[cons] = dict(p[cons])
-        p[cons]["w"] = _permute_in(p[cons]["w"], _in_axis(p, cons), outer,
-                                   size, idx)
-    return p
+    """The first channels of every group, as many as ``alpha`` keeps."""
+    p = flat(params)
+    n = widths(model, alpha)
+    for g in model.width_groups():
+        for e in g.entries:
+            p[e.path] = _unview(jax.lax.slice_in_dim(
+                _view(p[e.path], e, g.size), 0, n[g.name],
+                axis=e.axis + 1), e)
+    return nest(p)
 
 
-def expand(model, sub_update, full_shapes) -> tuple[dict, dict]:
-    """Zero-pad a sub-model update to full width; also the coverage mask."""
+def expand(model, sub_update, full_shapes: dict) -> tuple[dict, dict]:
+    """Zero-pad a sub-model update to the full shapes ``{path: shape}``;
+    also the coverage mask."""
+    sub = flat(sub_update)
+    cuts = [(g.size, e) for g in model.width_groups() for e in g.entries]
     upd, mask = {}, {}
-    for layer, leaves in full_shapes.items():
-        upd[layer], mask[layer] = {}, {}
-        for leaf, shape in leaves.items():
-            u = sub_update[layer][leaf]
-            m = jnp.ones_like(u)
-            for _, size, prod, cons, outer in model.GROUPS:
-                if layer == prod and leaf in ("w", "b"):
-                    axis = u.ndim - 1
-                    o = 1
-                elif layer == cons and leaf == "w":
-                    axis = 2 if u.ndim == 4 else 0
-                    o = outer
-                else:
-                    continue
-                n = u.shape[axis] // o
-                u = _pad_in(u, axis, o, n, size)
-                m = _pad_in(m, axis, o, n, size)
-            if u.shape != tuple(shape):
-                raise ValueError(f"{layer}.{leaf}: expanded to {u.shape}, "
-                                 f"the model has {tuple(shape)}")
-            upd[layer][leaf], mask[layer][leaf] = u, m
-    return upd, mask
+    for path, shape in full_shapes.items():
+        u = sub[path]
+        m = jnp.ones_like(u)
+        for size, e in cuts:
+            if e.path == path:
+                u, m = _pad(u, e, size), _pad(m, e, size)
+        if u.shape != tuple(shape):
+            raise ValueError(f"{path}: expanded to {u.shape}, the model "
+                             f"has {tuple(shape)}")
+        upd[path], mask[path] = u, m
+    return nest(upd), nest(mask)
 
 
-def _pad_in(x, axis, outer, n, size):
-    shape = x.shape
-    v = x.reshape(shape[:axis] + (outer, n) + shape[axis + 1:])
+def _pad(x, e: Entry, size: int):
+    n = x.shape[e.axis] // (e.outer * e.block)
+    v = _view(x, e, n)
     pads = [(0, 0)] * v.ndim
-    pads[axis + 1] = (0, size - n)
-    v = jnp.pad(v, pads)
-    return v.reshape(shape[:axis] + (outer * size,) + shape[axis + 1:])
+    pads[e.axis + 1] = (0, size - n)
+    return _unview(jnp.pad(v, pads), e)
 
 
 # ---------------------------------------------------------------------- FGC
 
-def fgc(model, update, beta, key):
+def fgc(update, beta, key):
     """Kernel-wise top-K sparsification and stochastic quantization of
-    the whole update as one vector.  Returns (values, sparsity mask)."""
-    names = leaf_names(model)
-    leaves = [update[a][b] for a, b in names]
+    the whole update as one vector, its leaves in JAX's order.  Returns
+    (values, sparsity mask)."""
+    leaves, tree = jax.tree.flatten(update)
     vec = jnp.concatenate([x.reshape(-1) for x in leaves])
     seg, kid = [], 0
     for x in leaves:
@@ -237,12 +255,9 @@ def fgc(model, update, beta, key):
     level = jnp.clip(base + (jax.random.uniform(key, vec.shape)
                              < pos - base), 0.0, n_levels)
     q = jnp.where(live, (lo + level * step) * jnp.sign(vec), 0.0)
-    values, masks, at = {}, {}, 0
-    for (a, b), x in zip(names, leaves):
-        values.setdefault(a, {})[b] = q[at:at + x.size].reshape(x.shape)
-        masks.setdefault(a, {})[b] = mask[at:at + x.size].reshape(x.shape)
-        at += x.size
-    return values, masks
+    ends = np.cumsum([x.size for x in leaves])[:-1]
+    return tuple(tree.unflatten([part.reshape(x.shape) for part, x in zip(
+        jnp.split(a, ends), leaves)]) for a in (q, mask))
 
 
 # ------------------------------------------------------------------- round
@@ -252,8 +267,7 @@ class Client(NamedTuple):
     alpha: float
     beta: float
     key: jax.Array
-    images: np.ndarray     # (steps, B, H, W, C)
-    labels: np.ndarray     # (steps, B)
+    batches: dict          # the minibatch dict, each leaf (steps, B, ...)
 
 
 def aio_weight(alpha: float, beta: float) -> float:
@@ -264,33 +278,31 @@ def aio_weight(alpha: float, beta: float) -> float:
 @functools.lru_cache(maxsize=None)
 def _client_fn(model, alpha: float, lr: float, dtype: str,
                half_batch: bool, highest: bool):
-    shapes = param_shapes(model)
+    shapes = flat(model.param_shapes())
     prec = HIGHEST if highest else None
 
-    def run(sorted_params, images, labels, beta, key):
+    def run(sorted_params, batches, beta, key):
         sub = shrink(model, sorted_params, alpha)
         sub = jax.tree.map(lambda x: x.astype(dtype), sub)
         if half_batch:
-            b = images.shape[1] // 2
-            images, labels = images[:, :b], labels[:, :b]
+            batches = jax.tree.map(lambda x: x[:, : x.shape[1] // 2],
+                                   batches)
 
         def step(p, batch):
-            x, y = batch
-            g = jax.grad(lambda q: cross_entropy(
-                forward(model, q, x, prec), y))(p)
+            g = jax.grad(lambda q: model.loss(q, batch, prec))(p)
             return jax.tree.map(lambda a, d: a - lr * d.astype(a.dtype),
                                 p, g), None
 
-        trained, _ = jax.lax.scan(step, sub, (images, labels))
+        trained, _ = jax.lax.scan(step, sub, batches)
         update = jax.tree.map(lambda a, t: a.astype(jnp.float32)
                               - t.astype(jnp.float32), sub, trained)
-        flat = jnp.concatenate([u.reshape(-1)
-                                for u in jax.tree.leaves(update)])
+        flat_update = jnp.concatenate([u.reshape(-1)
+                                       for u in jax.tree.leaves(update)])
         full, width_mask = expand(model, update, shapes)
-        values, mask = fgc(model, full, beta, key)
+        values, mask = fgc(full, beta, key)
         mask = jax.tree.map(jnp.multiply, mask, width_mask)
         return (jax.tree.map(jnp.multiply, values, mask), mask,
-                jnp.sum(jnp.square(flat)), flat)
+                jnp.sum(jnp.square(flat_update)), flat_update)
 
     return jax.jit(run)
 
@@ -328,14 +340,14 @@ def fl_round(model, params, clients: list[Client], lr: float, *,
     for c in clients:
         fn = _client_fn(model, float(c.alpha), float(lr), dtype, half_batch,
                         highest)
-        values, mask, sq, flat = fn(sorted_params, jnp.asarray(c.images),
-                                    jnp.asarray(c.labels),
-                                    jnp.float32(c.beta), c.key)
+        values, mask, sq, flat_update = fn(
+            sorted_params, jax.tree.map(jnp.asarray, c.batches),
+            jnp.float32(c.beta), c.key)
         num, den = _absorb(num, den, values, mask,
                            jnp.float32(aio_weight(c.alpha, c.beta)))
         change_sq.append(sq)
         if keep:
-            updates.append(np.asarray(flat))
+            updates.append(np.asarray(flat_update))
     return (sorted_params, _apply(sorted_params, num, den),
             float(sum(change_sq)), updates)
 
@@ -343,49 +355,47 @@ def fl_round(model, params, clients: list[Client], lr: float, *,
 class Rounds(NamedTuple):
     """What one side made of the checked rounds, round by round, and of
     the first round in full."""
-    deltas: list        # {(layer, leaf): norm of the change}
+    deltas: list        # {leaf path: norm of the change}
     losses: list        # test loss after the round
     change_sq: list     # squared norm of the clients' local changes
     updates: list       # first round: each client's local change, flat
-    change: dict        # first round: (layer, leaf) -> new - sorted
+    change: dict        # first round: leaf path -> new - sorted
 
 
-def follow(model, seed: int, rounds: list, lr: float, test, *,
+def follow(model, seed: int, rounds: list, lr: float, test: dict, *,
            keep: bool = False, **kw) -> Rounds:
     """Follow the checked rounds from the benchmark's weights for
     ``seed``.  ``rounds`` holds each round's list of :class:`Client`;
-    ``keep`` keeps the first round in full; ``kw`` goes to
-    :func:`fl_round`."""
-    params = jax.jit(init_params, static_argnums=0)(
-        model, jax.random.split(seed_key(seed))[1])
+    ``test`` is the test split as one minibatch dict; ``keep`` keeps the
+    first round in full; ``kw`` goes to :func:`fl_round`."""
+    params = jax.jit(model.init)(jax.random.split(seed_key(seed))[1])
     out = Rounds([], [], [], [], {})
     for i, clients in enumerate(rounds):
         sorted_p, params, sq, updates = fl_round(
             model, params, clients, lr, keep=keep and i == 0, **kw)
-        out.deltas.append({(a, b): float(jnp.linalg.norm(
-            (params[a][b] - sorted_p[a][b]).reshape(-1)))
-            for a in params for b in params[a]})
+        new, old = flat(params), flat(sorted_p)
+        out.deltas.append({k: float(jnp.linalg.norm((x - old[k]).reshape(
+            -1))) for k, x in new.items()})
         if keep and i == 0:
             out.updates.extend(updates)
-            out.change.update(jax.device_get({
-                (a, b): params[a][b] - sorted_p[a][b]
-                for a in params for b in params[a]}))
-        out.losses.append(test_loss(model, params, test.x, test.y))
+            out.change.update(jax.device_get({k: x - old[k]
+                                              for k, x in new.items()}))
+        out.losses.append(test_loss(model, params, test))
         out.change_sq.append(sq)
     return out
 
 
 @functools.lru_cache(maxsize=None)
 def _loss_sum(model):
-    return jax.jit(lambda p, x, y: cross_entropy(forward(model, p, x), y)
-                   * x.shape[0])
+    return jax.jit(lambda p, batch: model.loss(p, batch)
+                   * jax.tree.leaves(batch)[0].shape[0])
 
 
-def test_loss(model, params, images, labels, block: int = 1000) -> float:
-    """Mean cross entropy over the test set, in blocks of rows."""
-    total, n = 0.0, len(labels)
+def test_loss(model, params, rows: dict, block: int = 1000) -> float:
+    """Mean loss over a split given as one minibatch dict, in blocks of
+    rows."""
+    total, n = 0.0, len(jax.tree.leaves(rows)[0])
     for i in range(0, n, block):
-        total += float(_loss_sum(model)(params,
-                                        jnp.asarray(images[i:i + block]),
-                                        jnp.asarray(labels[i:i + block])))
+        total += float(_loss_sum(model)(params, jax.tree.map(
+            lambda x: jnp.asarray(x[i:i + block]), rows)))
     return total / n

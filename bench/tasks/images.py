@@ -4,6 +4,9 @@ Each class is a smoothed random template; a sample is its class template
 shifted by up to 2 pixels each way, plus Gaussian noise, clipped to
 [0, 1].  Train and test share the templates.  One jitted call makes both
 splits; the program gets them as host arrays, as it would load a dataset.
+The configuration gives ``image_shape`` (H, W, C), ``n_classes``,
+``n_train`` and ``n_test``.  A minibatch is ``{"images": (..., H, W, C)
+float32, "labels": (...) int32}``.
 """
 from __future__ import annotations
 
@@ -13,6 +16,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+PROGRAM_TASK = "make_image_task"
 
 
 class Split(NamedTuple):
@@ -52,9 +57,24 @@ def _nhwc(x, shape) -> np.ndarray:
     return np.ascontiguousarray(x.transpose(0, 2, 3, 1))
 
 
-def make_task(key, n_train: int, n_test: int, shape, n_classes: int
-              ) -> tuple[Split, Split]:
-    shape = tuple(shape)
-    (xt, yt), (xe, ye) = _make(key, n_train, n_test, shape, n_classes)
+def make(key, config: dict) -> tuple[Split, Split]:
+    shape = tuple(config["image_shape"])
+    (xt, yt), (xe, ye) = _make(key, config["n_train"], config["n_test"],
+                               shape, config["n_classes"])
     return (Split(_nhwc(xt, shape), np.asarray(yt)),
             Split(_nhwc(xe, shape), np.asarray(ye)))
+
+
+def rows(split: Split) -> dict:
+    return {"images": split.x, "labels": split.y}
+
+
+def zero_batch(config: dict, steps: int, batch: int) -> dict:
+    return {"images": np.zeros((steps, batch) + tuple(config["image_shape"]),
+                               np.float32),
+            "labels": np.zeros((steps, batch), np.int32)}
+
+
+def bytes_per_sample(config: dict) -> int:
+    """A float32 image and an int32 label."""
+    return 4 * int(np.prod(config["image_shape"])) + 4

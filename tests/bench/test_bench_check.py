@@ -97,9 +97,9 @@ def test_control_in_bfloat16_fails_the_limits():
 
 def test_reference_init_is_seeded():
     model = reference.load_model("fmnist-cnn")
-    a = reference.init_params(model, reference.seed_key(2**33 + 1))
-    b = reference.init_params(model, reference.seed_key(2**33 + 1))
-    c = reference.init_params(model, reference.seed_key(1))
+    a = model.init(reference.seed_key(2**33 + 1))
+    b = model.init(reference.seed_key(2**33 + 1))
+    c = model.init(reference.seed_key(1))
     assert (a["conv1"]["w"] == b["conv1"]["w"]).all()
     assert not (a["conv1"]["w"] == c["conv1"]["w"]).all()
     assert BENCH.is_dir()
