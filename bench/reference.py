@@ -27,7 +27,11 @@ else in ``bench/``.  A model file, ``models/<arch>.py``, provides
 * ``layer_flops(shapes)`` and ``train_flops(shapes)``: forward FLOPs per
   sample of each layer, and forward plus backward FLOPs per sample, of the
   model whose leaves have the shapes ``{path: shape}``;
-* ``width_groups()``: its EMS width groups, a tuple of :class:`WidthGroup`.
+* ``width_groups()``: its EMS width groups, a tuple of :class:`WidthGroup`;
+* ``PASS_ROWS``, optional: the most rows one pass of ``loss`` may take,
+  1000 where it is not given.  Local training takes a larger minibatch in
+  equal passes and averages their gradients; the test loss runs in blocks
+  of that many rows.
 
 A task file, ``tasks/<TASK>.py``, provides
 
@@ -220,44 +224,68 @@ def _pad(x, e: Entry, size: int):
 
 # ---------------------------------------------------------------------- FGC
 
+def _kernel_norms(x):
+    """L2 norm of each kernel of a leaf: its slices along the last axis, or
+    the whole leaf where it has fewer than two axes."""
+    if x.ndim < 2:
+        return jnp.sqrt(jnp.sum(x * x)).reshape(1)
+    return jnp.sqrt(jnp.sum(x * x, axis=tuple(range(x.ndim - 1))))
+
+
+def _kth_largest(x, k):
+    """The ``k``-th largest of the non-negative floats ``x``, exactly, for
+    ``1 <= k <= x.size``: a bisection over their bit patterns, which order
+    such floats as integers do.  A sort gives the same value, but compiles
+    far slower on the TPU at tens of thousands of kernels."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+
+    def step(i, t):
+        c = t | jnp.left_shift(1, 30 - i)
+        return jnp.where(jnp.sum(bits >= c) >= k, c, t)
+
+    return jax.lax.bitcast_convert_type(
+        jax.lax.fori_loop(0, 31, step, jnp.int32(0)), x.dtype)
+
+
 def fgc(update, beta, key):
-    """Kernel-wise top-K sparsification and stochastic quantization of
-    the whole update as one vector, its leaves in JAX's order.  Returns
-    (values, sparsity mask)."""
+    """Kernel-wise top-K sparsification and stochastic quantization of the
+    update, leaf by leaf.  A kernel is a slice along a leaf's last axis (a
+    leaf of fewer than two axes is one kernel); the top ``ceil((1 - rho)
+    K)`` of all K kernels by L2 norm survive.  The rounding draws one
+    uniform per element, the leaves in JAX's order.  Returns (values,
+    sparsity mask)."""
     leaves, tree = jax.tree.flatten(update)
-    vec = jnp.concatenate([x.reshape(-1) for x in leaves])
-    seg, kid = [], 0
-    for x in leaves:
-        if x.ndim >= 2:
-            k = x.shape[-1]
-            seg.append(np.tile(np.arange(k), x.size // k) + kid)
-        else:
-            k = 1
-            seg.append(np.full(x.size, kid))
-        kid += k
-    seg = jnp.asarray(np.concatenate(seg).astype(np.int32))
     sb = jnp.sqrt(jnp.asarray(beta, jnp.float32))
     rho = 1.0 - sb
     n_levels = jnp.clip(jnp.exp2(32.0 * sb), 2.0, 65535.0)
-    norms = jnp.sqrt(jax.ops.segment_sum(vec * vec, seg, num_segments=kid))
+    norms = [_kernel_norms(x) for x in leaves]
+    every = jnp.concatenate(norms)
+    kid = every.size
     kept = jnp.ceil((1.0 - rho) * kid)
-    thr = jnp.sort(norms)[jnp.clip(kid - kept, 0, kid - 1).astype(jnp.int32)]
-    mask = (norms >= thr)[seg].astype(jnp.float32)
-    mag = jnp.abs(vec) * mask
-    live = mask > 0
-    lo = jnp.min(jnp.where(live & (mag > 0), mag, jnp.inf))
+    thr = _kth_largest(every, jnp.clip(kept, 1, kid).astype(jnp.int32))
+    live = [jnp.broadcast_to((n >= thr).reshape(x.shape[-1:] if x.ndim >= 2
+                                                 else ()), x.shape)
+            for n, x in zip(norms, leaves)]
+    mags = [jnp.abs(x) * v.astype(jnp.float32) for x, v in zip(leaves, live)]
+    lo = functools.reduce(jnp.minimum, [
+        jnp.min(jnp.where(v & (m > 0), m, jnp.inf))
+        for m, v in zip(mags, live)])
     lo = jnp.where(jnp.isfinite(lo), lo, 0.0)
-    hi = jnp.max(jnp.where(live, mag, -jnp.inf))
+    hi = functools.reduce(jnp.maximum, [
+        jnp.max(jnp.where(v, m, -jnp.inf)) for m, v in zip(mags, live)])
     hi = jnp.where(jnp.isfinite(hi), hi, 0.0)
     step = jnp.maximum(hi - lo, 1e-20) / n_levels
-    pos = jnp.clip((mag - lo) / step, 0.0, n_levels)
-    base = jnp.floor(pos)
-    level = jnp.clip(base + (jax.random.uniform(key, vec.shape)
-                             < pos - base), 0.0, n_levels)
-    q = jnp.where(live, (lo + level * step) * jnp.sign(vec), 0.0)
-    ends = np.cumsum([x.size for x in leaves])[:-1]
-    return tuple(tree.unflatten([part.reshape(x.shape) for part, x in zip(
-        jnp.split(a, ends), leaves)]) for a in (q, mask))
+    draw = jax.random.uniform(key, (sum(x.size for x in leaves),))
+    values, at = [], 0
+    for x, m, v in zip(leaves, mags, live):
+        pos = jnp.clip((m - lo) / step, 0.0, n_levels)
+        base = jnp.floor(pos)
+        u = draw[at:at + x.size].reshape(x.shape)
+        level = jnp.clip(base + (u < pos - base), 0.0, n_levels)
+        values.append(jnp.where(v, (lo + level * step) * jnp.sign(x), 0.0))
+        at += x.size
+    return (tree.unflatten(values),
+            tree.unflatten([v.astype(jnp.float32) for v in live]))
 
 
 # ------------------------------------------------------------------- round
@@ -275,9 +303,26 @@ def aio_weight(alpha: float, beta: float) -> float:
     return 1.0 / max(d * d, 1e-12)
 
 
+PASS_ROWS = 1000    # rows in one pass of a model file that names none
+
+
+def _passes(model, rows: int) -> int:
+    """Passes of equal size, of at most the model's ``PASS_ROWS`` rows
+    each, that a minibatch of ``rows`` rows takes."""
+    per = getattr(model, "PASS_ROWS", PASS_ROWS)
+    n = -(-rows // per)
+    if rows % n:
+        raise ValueError(f"{rows} rows do not split into {n} equal passes "
+                         f"of at most {per} rows")
+    return n
+
+
 @functools.lru_cache(maxsize=None)
 def _client_fn(model, alpha: float, lr: float, dtype: str,
-               half_batch: bool, highest: bool):
+               half_batch: bool, highest: bool, keep: bool):
+    """The client's round as one program: (masked values, mask as bool,
+    squared norm of the local change, and with ``keep`` the local change
+    itself)."""
     shapes = flat(model.param_shapes())
     prec = HIGHEST if highest else None
 
@@ -288,36 +333,61 @@ def _client_fn(model, alpha: float, lr: float, dtype: str,
             batches = jax.tree.map(lambda x: x[:, : x.shape[1] // 2],
                                    batches)
 
+        def grad(p, batch):
+            return jax.grad(lambda q: model.loss(q, batch, prec))(p)
+
         def step(p, batch):
-            g = jax.grad(lambda q: model.loss(q, batch, prec))(p)
+            n = _passes(model, len(jax.tree.leaves(batch)[0]))
+            if n == 1:
+                g = grad(p, batch)
+            else:
+                # the minibatch's mean loss is the mean of its equal
+                # passes' means: their gradients summed, then divided
+                g, _ = jax.lax.scan(
+                    lambda acc, part: (jax.tree.map(jnp.add, acc,
+                                                    grad(p, part)), None),
+                    jax.tree.map(jnp.zeros_like, p),
+                    jax.tree.map(lambda x: x.reshape((n, -1) + x.shape[1:]),
+                                 batch))
+                g = jax.tree.map(lambda d: d / n, g)
             return jax.tree.map(lambda a, d: a - lr * d.astype(a.dtype),
                                 p, g), None
 
         trained, _ = jax.lax.scan(step, sub, batches)
         update = jax.tree.map(lambda a, t: a.astype(jnp.float32)
                               - t.astype(jnp.float32), sub, trained)
-        flat_update = jnp.concatenate([u.reshape(-1)
-                                       for u in jax.tree.leaves(update)])
+        # one sum over the update as one vector: a sum of per-leaf sums
+        # would round otherwise, and ``train_gap`` reads this number
+        sq = jnp.sum(jnp.square(jnp.concatenate(
+            [u.reshape(-1) for u in jax.tree.leaves(update)])))
         full, width_mask = expand(model, update, shapes)
         values, mask = fgc(full, beta, key)
         mask = jax.tree.map(jnp.multiply, mask, width_mask)
-        return (jax.tree.map(jnp.multiply, values, mask), mask,
-                jnp.sum(jnp.square(flat_update)), flat_update)
+        out = (jax.tree.map(jnp.multiply, values, mask),
+               jax.tree.map(lambda m: m > 0, mask), sq)
+        return out + (update,) if keep else out
 
     return jax.jit(run)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0, 1))
 def _absorb(num, den, values, mask, w):
-    return (jax.tree.map(lambda n, v, m: n + w * m * v, num, values, mask),
-            jax.tree.map(lambda d, m: d + w * m, den, mask))
+    return (jax.tree.map(lambda n, v, m: n + w * m.astype(n.dtype) * v,
+                         num, values, mask),
+            jax.tree.map(lambda d, m: d + w * m.astype(d.dtype), den, mask))
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=1)
 def _apply(sorted_params, num, den):
     agg = jax.tree.map(lambda n, d: jnp.where(d > 0, n / jnp.maximum(
         d, 1e-12), 0.0), num, den)
     return jax.tree.map(jnp.subtract, sorted_params, agg)
+
+
+@functools.lru_cache(maxsize=None)
+def _sort_fn(model):
+    return jax.jit(functools.partial(sort_channels, model),
+                   donate_argnums=0)
 
 
 def fl_round(model, params, clients: list[Client], lr: float, *,
@@ -326,28 +396,31 @@ def fl_round(model, params, clients: list[Client], lr: float, *,
              ) -> tuple[dict, dict, float, Optional[list]]:
     """One synchronous round.  Returns (sorted params, new params, the
     squared norm of every client's local change before FGC, summed, and
-    with ``keep`` each client's local change as a flat vector).
+    with ``keep`` each client's local change as a flat vector).  ``params``
+    is donated: the round sorts it in place of its own buffers.
 
     Local training runs its products at ``Precision.HIGHEST`` in float32
     and at the default precision in a lower ``dtype``, unless ``highest``
     says otherwise."""
     if highest is None:
         highest = dtype == "float32"
-    sorted_params = jax.jit(functools.partial(sort_channels, model))(params)
+    sorted_params = _sort_fn(model)(params)
     num = jax.tree.map(jnp.zeros_like, sorted_params)
     den = jax.tree.map(jnp.zeros_like, sorted_params)
     change_sq, updates = [], [] if keep else None
     for c in clients:
         fn = _client_fn(model, float(c.alpha), float(lr), dtype, half_batch,
-                        highest)
-        values, mask, sq, flat_update = fn(
+                        highest, keep)
+        values, mask, sq, *update = fn(
             sorted_params, jax.tree.map(jnp.asarray, c.batches),
             jnp.float32(c.beta), c.key)
         num, den = _absorb(num, den, values, mask,
                            jnp.float32(aio_weight(c.alpha, c.beta)))
+        del values, mask    # freed before the next client's program runs
         change_sq.append(sq)
         if keep:
-            updates.append(np.asarray(flat_update))
+            updates.append(np.concatenate([np.asarray(u).reshape(-1) for u
+                                           in jax.tree.leaves(update)]))
     return (sorted_params, _apply(sorted_params, num, den),
             float(sum(change_sq)), updates)
 
@@ -380,6 +453,7 @@ def follow(model, seed: int, rounds: list, lr: float, test: dict, *,
             out.updates.extend(updates)
             out.change.update(jax.device_get({k: x - old[k]
                                               for k, x in new.items()}))
+        del sorted_p, old
         out.losses.append(test_loss(model, params, test))
         out.change_sq.append(sq)
     return out
@@ -391,10 +465,11 @@ def _loss_sum(model):
                    * jax.tree.leaves(batch)[0].shape[0])
 
 
-def test_loss(model, params, rows: dict, block: int = 1000) -> float:
+def test_loss(model, params, rows: dict) -> float:
     """Mean loss over a split given as one minibatch dict, in blocks of
-    rows."""
+    the model's ``PASS_ROWS`` rows."""
     total, n = 0.0, len(jax.tree.leaves(rows)[0])
+    block = getattr(model, "PASS_ROWS", PASS_ROWS)
     for i in range(0, n, block):
         total += float(_loss_sum(model)(params, jax.tree.map(
             lambda x: jnp.asarray(x[i:i + block]), rows)))
